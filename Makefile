@@ -26,7 +26,7 @@ test:
 	$(PY) -m pytest tests/ -q
 
 lint:
-	$(PY) -m compileall -q open_simulator_tpu tools tests bench.py __graft_entry__.py
+	$(PY) -m compileall -q open_simulator_tpu tools tests bench.py __graft_entry__.py chip_smoke.py
 	$(PY) -m tools.simonlint $(if $(NO_LINT_CACHE),--no-cache,)
 
 check: lint test

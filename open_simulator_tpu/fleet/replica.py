@@ -63,6 +63,38 @@ DEFAULT_SPAWN_ATTEMPTS = 4
 DEFAULT_READY_TIMEOUT_S = 180.0
 
 
+def replicas_on_chips() -> bool:
+    """Whether serve children started from here would run on TPU
+    chips: ``JAX_PLATFORMS`` does not pin them to the CPU and the PCI
+    bus shows TPU chips. Reads sysfs only — the supervisor must never
+    take the chip itself to decide this (a parent holding the chip
+    hangs every child that needs it)."""
+    platforms = [
+        p.strip().lower()
+        for p in os.environ.get("JAX_PLATFORMS", "").split(",")
+        if p.strip()
+    ]
+    if platforms and "tpu" not in platforms:
+        return False
+    from jax._src.hardware_utils import num_available_tpu_chips_and_device_id
+
+    chips, _ = num_available_tpu_chips_and_device_id()
+    return chips > 0
+
+
+def check_replica_count(replicas: int) -> None:
+    """Refuse more than one replica on a TPU host: each replica would
+    take every chip it sees, so the second one fails or hangs until
+    the fleet can pin one replica per chip (ROADMAP B)."""
+    if replicas > 1 and replicas_on_chips():
+        raise InputError(
+            f"--replicas {replicas} on a TPU host: each serve replica "
+            "would take every chip, and the fleet cannot pin one replica "
+            "per chip yet; run --replicas 1, or set JAX_PLATFORMS=cpu "
+            "for a CPU fleet"
+        )
+
+
 class DoubleSpawnError(InputError):
     """A second replica was spawned against a slot whose lock holder
     is still alive — split-brain on the slot's snapshot journal.

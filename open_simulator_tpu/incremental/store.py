@@ -21,10 +21,10 @@ on-disk store:
   answer;
 - serialization rides ``jax.experimental.serialize_executable``; on
   backends where executable export is unsupported the store degrades
-  to enabling JAX's own persistent compilation cache rooted in the
-  same directory (``xla-cache/``), keyed by jax's hashes instead of
-  ours — cold starts still skip XLA, only the loaded-cost bookkeeping
-  is lost.
+  to enabling JAX's own persistent compilation cache in its one
+  directory (utils/compile_cache.py), keyed by jax's hashes instead
+  of ours — cold starts still skip XLA, only the loaded-cost
+  bookkeeping is lost.
 
 The load path is a guard seam (``aot.store_load`` injection point):
 classified faults degrade to a counted miss + recompile, identical
@@ -67,7 +67,7 @@ MODE_ENV = "SIMON_AOT_STORE_MODE"
 
 #: bump when the entry layout changes — old entries then digest-miss
 #: (they were keyed with the old schema string) instead of misparsing
-_SCHEMA = "simon-aot-1"
+_SCHEMA = "simon-aot-2"
 _MAGIC = b"SIMONAOT\n"
 
 #: faults at the load seam that degrade to a counted recompile; an
@@ -248,11 +248,16 @@ class ArtifactStore:
         from ..obs.costs import CostRecord
 
         try:
+            import jax
             from jax.experimental import serialize_executable
 
             ser, in_tree, out_tree = pickle.loads(payload)
+            # load onto the devices the executable was compiled for:
+            # left unset, JAX loads it for EVERY device of the backend
+            by_id = {d.id: d for d in jax.devices()}
             compiled = serialize_executable.deserialize_and_load(
-                ser, in_tree, out_tree
+                ser, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in header["devices"]],
             )
         except Exception as e:  # noqa: BLE001 - any rehydration fault degrades to a counted reject + recompile; the compile path surfaces real errors
             log.warning(
@@ -290,6 +295,9 @@ class ArtifactStore:
             from jax.experimental import serialize_executable
 
             payload = pickle.dumps(serialize_executable.serialize(compiled))
+            devices = [
+                d.id for d in compiled.runtime_executable().local_devices()
+            ]
         except Exception as e:  # noqa: BLE001 - export support is backend-optional: probe result decides the fallback, never crashes the dispatch
             enable = False
             with self._lock:
@@ -300,8 +308,8 @@ class ArtifactStore:
                 log.warning(
                     "aot store: executable serialization unavailable "
                     "on this backend (%s); falling back to the JAX "
-                    "persistent compilation cache under %s",
-                    str(e).split("\n", 1)[0][:120], self.root,
+                    "persistent compilation cache",
+                    str(e).split("\n", 1)[0][:120],
                 )
                 self._enable_jax_cache()
             return False
@@ -313,6 +321,7 @@ class ArtifactStore:
             "site": site,
             "tool": self.tool,
             "payload_sha256": hashlib.sha256(payload).hexdigest(),
+            "devices": devices,
             "cost": dict(rec.as_dict(), site=site),
         }
         hbytes = json.dumps(header, sort_keys=True).encode()
@@ -351,15 +360,17 @@ class ArtifactStore:
 
     def _enable_jax_cache(self) -> None:
         """Best-effort enablement of JAX's persistent compilation cache
-        rooted inside the store directory — the degraded mode for
-        backends without executable export. Thresholds open wide so
-        even sub-second compiles persist."""
+        in its one directory (utils/compile_cache.py; a directory
+        already set stays) — the degraded mode for backends without
+        executable export. Thresholds open wide so even sub-second
+        compiles persist."""
         try:
             import jax
 
-            cache_dir = os.path.join(self.root, "xla-cache")
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            from ..utils.compile_cache import configure_compile_cache
+
+            cache_dir = configure_compile_cache()
+            log.info("aot store: persistent compilation cache at %s", cache_dir)
             for knob, value in (
                 ("jax_persistent_cache_min_compile_time_secs", 0),
                 ("jax_persistent_cache_min_entry_size_bytes", -1),

@@ -101,8 +101,7 @@ def parse_mesh_spec(spec: Optional[str]) -> Optional[int]:
 def configure(spec: Optional[str]) -> None:
     """Set the process-wide mesh selection (CLI ``--mesh`` / SIMON_MESH).
     Validates the spec eagerly (InputError on junk) but resolves
-    devices lazily — configure() must be callable before the platform
-    is forced (cli._force_platform)."""
+    devices lazily — configure() must not initialize the backend."""
     parse_mesh_spec(spec)  # validate now, resolve at first current_mesh()
     with _LOCK:
         _STATE["spec"] = spec if spec is not None else "off"
@@ -395,13 +394,25 @@ class _ShardCtx:
 
     def combine_max(self, x):
         import jax
+        import jax.numpy as jnp
 
-        return jax.lax.pmax(x, self.axis)
+        return self._extremum(x, jax.lax.pmax, jnp.max)
 
     def combine_min(self, x):
         import jax
+        import jax.numpy as jnp
 
-        return jax.lax.pmin(x, self.axis)
+        return self._extremum(x, jax.lax.pmin, jnp.min)
+
+    def _extremum(self, x, collective, reduce):
+        # the TPU lowers only SUM all-reduces of 64-bit integers (the
+        # chip's compiler refuses an s64 pmax): gather the shards'
+        # values and reduce locally — exact, and one small gather
+        import jax
+
+        if x.dtype.itemsize == 8:
+            return reduce(jax.lax.all_gather(x, self.axis), axis=0)
+        return collective(x, self.axis)
 
     def combine_sum(self, x):
         import jax
@@ -438,18 +449,17 @@ class _ShardCtx:
         return (jax.lax.psum(contrib, self.axis) - 1).astype(arr.dtype)
 
     def first_max_index(self, masked):
-        import jax
         import jax.numpy as jnp
 
         n_l = masked.shape[0]
         local_best = jnp.argmax(masked).astype(jnp.int64)
         local_max = masked[local_best]
-        global_max = jax.lax.pmax(local_max, self.axis)
+        global_max = self.combine_max(local_max)
         big = jnp.iinfo(jnp.int64).max
         cand = jnp.where(
             local_max == global_max, self._offset(n_l) + local_best, big
         )
-        return jax.lax.pmin(cand, self.axis)
+        return self.combine_min(cand)
 
     def commit_onehot(self, placement, commit, n_local):
         import jax
@@ -532,7 +542,6 @@ def _mesh_scan_jit(mesh):
         return cached
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..obs import profile
@@ -551,7 +560,7 @@ def _mesh_scan_jit(mesh):
             unsched = jnp.sum(placements == -1)
             cpu, mem, vg = _utilization_ctx(static_l, valid_l, final, ctx)
             # leading device axis instead of claiming replication:
-            # check_rep=False cannot verify replicated out_specs, so
+            # check_vma=False cannot verify replicated out_specs, so
             # each shard contributes one (identical) row and the host
             # reads row 0
             return (
@@ -559,7 +568,7 @@ def _mesh_scan_jit(mesh):
                 vg[None],
             )
 
-        sharded = shard_map(
+        sharded = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -571,7 +580,7 @@ def _mesh_scan_jit(mesh):
                 P(),
             ),
             out_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
-            check_rep=False,
+            check_vma=False,
         )
         return sharded(static, init, cls, pinned, node_valid, pod_active)
 

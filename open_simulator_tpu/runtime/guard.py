@@ -101,6 +101,33 @@ def note_downgrade(label: str, frm: str, to: str, reason: str, trace=None):
     log.warning("%s: downgrading %s -> %s (%s)", label, frm, to, reason)
 
 
+#: counters the ladder bumps when it degrades or predicts it must
+GUARD_COUNTERS = (
+    "guard_oom_reactive_total",
+    "guard_oom_predicted_total",
+    "guard_rung_predicted_skips_total",
+)
+
+
+def degradations(trace=None) -> dict:
+    """Every degradation on record: the ``*-downgrade`` /
+    ``*-serial-fallback`` notes of the trace (since its last reset) and
+    the nonzero guard counters. Empty on a clean run — the chip smoke and the bench
+    fail on anything here, so a kernel the chip refuses can never pass
+    as a slow success."""
+    from ..utils.trace import COUNTERS, GLOBAL
+
+    notes = (trace or GLOBAL).notes
+    found = {
+        k: v for k, v in notes.items()
+        if k.endswith("-downgrade") or k.endswith("-serial-fallback")
+    }
+    found.update(
+        {k: COUNTERS.get(k) for k in GUARD_COUNTERS if COUNTERS.get(k)}
+    )
+    return found
+
+
 def try_downgrade(e: BaseException, *, label: str, frm: str, to: str,
                   trace=None) -> bool:
     """One-rung downgrade for call sites that hold their own fallback

@@ -33,7 +33,7 @@ from .oracle import Oracle
 
 # shortest priority-bearing batch worth routing through the
 # priority-scan engine (_schedule_pods_priority) — encode + device
-# relay have fixed cost, so short batches are cheaper serially (tests
+# dispatch have fixed cost, so short batches are cheaper serially (tests
 # lower this to exercise the scan routes on tiny batches)
 MIN_SCAN_RUN = 64
 

@@ -443,7 +443,7 @@ def test_sigterm_drains_inflight_and_exits_zero(tmp_path):
     (drain, not abort) and exits 0."""
     cfg = _write_serve_config(tmp_path)
     env = dict(os.environ)
-    env.update({"JAX_PLATFORMS": "cpu", "SIMON_BACKEND_PROBE": "0"})
+    env.update({"JAX_PLATFORMS": "cpu"})
     stderr_path = tmp_path / "serve-stderr.log"
     proc = subprocess.Popen(
         [

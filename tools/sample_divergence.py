@@ -21,7 +21,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("SIMON_BACKEND_PROBE", "0")
 
 from open_simulator_tpu.models.decode import ResourceTypes
 from open_simulator_tpu.scheduler.core import AppResource, simulate

@@ -20,7 +20,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("SIMON_BACKEND_PROBE", "0")
 
 
 def build_at(n_nodes: int, flavor: str):

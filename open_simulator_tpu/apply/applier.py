@@ -199,7 +199,7 @@ def replay_masked(sweep, valid, placements):
     from ..scheduler.core import NodeStatus, SimulateResult, UnscheduledPod
     from ..scheduler.engine import build_bulk_tables
     from ..scheduler.oracle import ClassCommitCache, Oracle, simple_commit_mask
-    from ..utils.trace import profiled
+    from ..utils.trace import phase
 
     if EXPLAIN.enabled:
         EXPLAIN.set_context(engine="capacity-replay")
@@ -228,7 +228,7 @@ def replay_masked(sweep, valid, placements):
     pods = sweep.pods
     failed = []
     commit_cache = ClassCommitCache()
-    with profiled("engine/replay"):
+    with phase("engine/replay"):
         # event pods (inactive / pinned / failed / side-effect classes)
         # take the exact per-pod path in order; runs between them bulk
         bulk_mask = (
@@ -389,6 +389,8 @@ def probe_plan(
     `journal` makes probes and scenario verdicts resumable."""
     import gc
 
+    from ..utils.trace import phase
+
     # the plan allocates millions of short-lived dicts (pod expansion,
     # replay, report rows) but frees almost nothing mid-run — cyclic-GC
     # passes are pure overhead and wall-clock jitter at 100k pods.
@@ -403,10 +405,12 @@ def probe_plan(
             chaos_trials, budget, journal,
         )
     finally:
-        clear_all_memos()
+        with phase("apply/clear-memos"):
+            clear_all_memos()
         if gc_was_enabled:
-            gc.enable()
-            gc.collect()
+            with phase("apply/gc"):
+                gc.enable()
+                gc.collect()
 
 
 def _capacity_feasible():
@@ -484,8 +488,7 @@ def _probe_plan_inner(
     if journal is not None:
         sweep.attach_journal(journal)
     feasible, (max_cpu, max_mem, max_vg) = _capacity_feasible()
-    with phase("apply/lower-bound"):
-        start = sweep.lower_bound(max_cpu, max_mem, max_vg)
+    start = sweep.lower_bound(max_cpu, max_mem, max_vg)
     with phase("apply/probe-search"):
         best = sweep.find_min_count(feasible, start=start, budget=budget)
     fail_message = ""
@@ -532,12 +535,13 @@ def probe_plan_multi(
     probe_plan would produce for it."""
     import gc
 
+    from ..utils.trace import phase
+
     gc_was_enabled = gc.isenabled()
     if gc_was_enabled:
         gc.disable()
     try:
         from ..parallel.sweep import CapacitySweep, find_min_count_multi
-        from ..utils.trace import phase
 
         feasible, (max_cpu, max_mem, max_vg) = _capacity_feasible()
         jobs = []
@@ -553,8 +557,7 @@ def probe_plan_multi(
                 # greed ordering: later sweeps reuse the first's pods
                 share_pods_from=jobs[0][0] if jobs else None,
             )
-            with phase("apply/lower-bound"):
-                start = sweep.lower_bound(max_cpu, max_mem, max_vg)
+            start = sweep.lower_bound(max_cpu, max_mem, max_vg)
             jobs.append((sweep, feasible, start))
         with phase("apply/probe-search"):
             bests = find_min_count_multi(jobs, budget=budget)
@@ -582,10 +585,12 @@ def probe_plan_multi(
             for (sweep, _, _), best in zip(jobs, bests)
         ]
     finally:
-        clear_all_memos()
+        with phase("apply/clear-memos"):
+            clear_all_memos()
         if gc_was_enabled:
-            gc.enable()
-            gc.collect()
+            with phase("apply/gc"):
+                gc.enable()
+                gc.collect()
 
 
 class Applier:

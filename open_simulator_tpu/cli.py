@@ -41,20 +41,31 @@ def _setup_logging():
     logging.basicConfig(level=levels.get(level, logging.INFO), format="%(levelname)s %(message)s")
 
 
-def _obs_begin(args):
+def _obs_begin(args, holds_device: bool = True):
     """Arm the flight recorder (obs/) from the shared observability
     flags (--trace-out / --explain / --profile-dir; docs/OBSERVABILITY.md).
     Returns a finish callback that exports the trace and disarms —
-    called from _with_obs's finally so every exit path exports."""
-    from .obs import profile as obs_profile
+    called from _with_obs's finally so every exit path exports. A
+    command that `holds_device` takes one profiler capture of its whole
+    run; one that does not (the fleet supervisor) leaves the capture
+    to its children, which inherit SIMON_PROFILE_DIR."""
+    from .obs import profile  # noqa: F401 - installs the ledger's span-boundary hook before the root span
     from .obs import spans
     from .obs.explain import EXPLAIN
 
     trace_out = getattr(args, "trace_out", "")
     explain = getattr(args, "explain", None)
-    profile_dir = getattr(args, "profile_dir", "")
+    profile_dir = getattr(args, "profile_dir", "") or os.environ.get("SIMON_PROFILE_DIR", "")
+    capture = bool(profile_dir) and holds_device
     if profile_dir:
-        obs_profile.set_profile_dir(profile_dir)
+        os.environ["SIMON_PROFILE_DIR"] = profile_dir
+    if capture:
+        import jax
+
+        # one capture of the whole command; it initializes the backend,
+        # so it takes the chip
+        os.makedirs(profile_dir, exist_ok=True)
+        jax.profiler.start_trace(profile_dir)
     if trace_out:
         sink = spans.JsonlSink(trace_out) if trace_out.endswith(".jsonl") else None
         spans.RECORDER.enable(sink)
@@ -72,13 +83,17 @@ def _obs_begin(args):
         if explain is not None:
             EXPLAIN.disable()
         if profile_dir:
-            obs_profile.set_profile_dir(None)
-            print(f"JAX profiler capture(s) in {profile_dir}", file=sys.stderr)
+            os.environ.pop("SIMON_PROFILE_DIR", None)
+        if capture:
+            import jax
+
+            jax.profiler.stop_trace()
+            print(f"JAX profiler capture in {profile_dir}", file=sys.stderr)
 
     return finish
 
 
-def _with_obs(name: str):
+def _with_obs(name: str, holds_device: bool = True):
     """Decorator for the long-running commands: arm the recorder from
     the obs flags, run the command under a root span (`simon <name>` —
     phases and jit dispatches nest under it), export on ANY exit."""
@@ -90,7 +105,7 @@ def _with_obs(name: str):
         def wrapper(args):
             from .obs.spans import RECORDER
 
-            finish = _obs_begin(args)
+            finish = _obs_begin(args, holds_device)
             try:
                 with RECORDER.span(f"simon {name}", command=name):
                     return fn(args)
@@ -719,7 +734,7 @@ def cmd_serve(args) -> int:
     return code
 
 
-@_with_obs("fleet")
+@_with_obs("fleet", holds_device=False)
 def cmd_fleet(args) -> int:
     """N-replica serve fleet behind one consistent-hash router
     (fleet/; docs/FLEET.md): spawn N `simon serve` replicas sharing
@@ -1783,9 +1798,9 @@ def _add_obs_flags(p: argparse.ArgumentParser):
         "--profile-dir",
         default="",
         metavar="DIR",
-        help="capture JAX profiler traces of the device phases into DIR "
-        "(viewable in TensorBoard/Perfetto; equivalent to setting "
-        "SIMON_PROFILE_DIR)",
+        help="write one JAX profiler capture of the whole command, with "
+        "every phase annotated, into DIR (viewable in TensorBoard/"
+        "Perfetto; equivalent to setting SIMON_PROFILE_DIR)",
     )
 
 
@@ -1971,8 +1986,8 @@ def build_parser() -> argparse.ArgumentParser:
     p_apply.add_argument(
         "--trace",
         action="store_true",
-        help="print per-phase wall-clock JSON to stderr (set SIMON_PROFILE_DIR "
-        "for a JAX profiler capture of the scan phases)",
+        help="print per-phase wall-clock JSON to stderr (--profile-dir "
+        "adds a JAX profiler capture of the whole command)",
     )
     p_apply.set_defaults(func=cmd_apply)
 

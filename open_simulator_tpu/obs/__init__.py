@@ -12,7 +12,9 @@ hot path until a CLI flag turns them on:
   the serial oracle and the scan-replay paths — ``--explain [POD]``.
 - ``obs.profile``: JAX dispatch / jit-cache-miss (recompile) / device
   transfer-bytes accounting through the ``utils.trace.Counters``
-  registry, plus the ``--profile-dir`` JAX profiler capture.
+  registry, plus the ``--profile-dir`` JAX profiler capture: one
+  capture of the whole command, in which every ``utils.trace.phase``
+  is an annotation.
 
 The compiled-cost & memory observatory (r10) layers four more pieces
 on the same registry, all always-on:

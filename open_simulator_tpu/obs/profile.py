@@ -38,8 +38,10 @@ are always on (one lock + dict-add per DISPATCH, which is rare —
 dispatches are per scan round, not per pod), so there is no flag to
 forget before asking "did this workload recompile".
 
-The optional ``jax.profiler`` capture (``--profile-dir``) reuses the
-``utils.trace.profiled`` machinery via the SIMON_PROFILE_DIR env var.
+The optional ``jax.profiler`` capture (``--profile-dir DIR``, or
+SIMON_PROFILE_DIR) is one capture of the whole command, started and
+stopped by the CLI (``cli._obs_begin``); every ``utils.trace.phase`` is
+an annotation in it.
 """
 
 from __future__ import annotations
@@ -360,20 +362,6 @@ def nbytes_of(*arrays) -> int:
         if isinstance(nb, int):
             total += nb
     return total
-
-
-# ------------------------------------------------------ profiler capture
-
-
-def set_profile_dir(path: Optional[str]) -> None:
-    """Arm (or disarm with None) the ``utils.trace.profiled`` JAX
-    profiler capture — the --profile-dir CLI wiring. Captures land in
-    ``<path>/<phase-name>/`` and open in TensorBoard / Perfetto."""
-    if path:
-        os.makedirs(path, exist_ok=True)
-        os.environ["SIMON_PROFILE_DIR"] = path
-    else:
-        os.environ.pop("SIMON_PROFILE_DIR", None)
 
 
 # ------------------------------------------------------ snapshot helpers

@@ -2736,7 +2736,7 @@ def should_use() -> bool:
 def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
                 grouped: bool = False, interpret: bool = False):
     """The jitted fused-kernel call for one plan layout, cached per
-    layout: ``call(percall, flat_plan)`` over the two packed int32
+    layout: ``pod_scan_fused(percall, flat_plan)`` over the two packed int32
     buffers (percall: 8 pod-scalar rows, the active row and the node
     validity row, plus a trailing group offset when ``grouped``;
     flat_plan: the plan arrays of ``metas``). Built from shapes alone,
@@ -2843,8 +2843,10 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
     n_act = pr_rows * LANES
     n_val = plan.r * LANES
 
+    # the name is the kernel's in a profiler trace: module
+    # `jit_pod_scan_fused`, Pallas call `pod_scan_fused`
     @jax.jit
-    def call(percall, flat_plan):
+    def pod_scan_fused(percall, flat_plan):
         # both the per-call inputs and the plan ship as ONE packed
         # buffer each; the slices fuse into this program
         # (_unpack_flat) so no per-array device buffers ever
@@ -2892,6 +2894,7 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
             out_specs=tuple(out_specs),
             scratch_shapes=scratch,
             interpret=interpret,
+            name="pod_scan_fused",
         )(*arrays)
         # ONE output array (placements + 6 states + any VG usage
         # concatenated on the row axis): every host-blocking point
@@ -2902,8 +2905,8 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
             fetched.append(outs[7].reshape(sc.v * plan.r, LANES))
         return jnp.concatenate(fetched, axis=0)
 
-    _COMPILED_CACHE[key] = _Compiled(fn=call)
-    return call
+    _COMPILED_CACHE[key] = _Compiled(fn=pod_scan_fused)
+    return pod_scan_fused
 
 
 def run_scan_pallas(plan: PallasPlan, class_of_pod, pod_active, node_valid,

@@ -159,8 +159,9 @@ class CapacitySweep:
         from ..utils.trace import phase
 
         self.max_count = max_count if new_node_spec is not None else 0
-        padded = cluster.copy()
-        padded.nodes = list(padded.nodes) + _new_nodes(new_node_spec, self.max_count)
+        with phase("sweep/pad"):
+            padded = cluster.copy()
+            padded.nodes = list(padded.nodes) + _new_nodes(new_node_spec, self.max_count)
 
         # Build oracle at full padding; generate the full pod sequence
         # the serial path would see (cluster pods, then apps in order).
@@ -247,17 +248,18 @@ class CapacitySweep:
         # replay binds pods (replay_scenario writes nodeName into these
         # shared pod dicts; a later replay must not mistake a previous
         # replay's binding for an original pin)
-        self.had_node_name = np.array(
-            [bool((p.get("spec") or {}).get("nodeName")) for p in pods], dtype=bool
-        )
-        # daemonset pods of disabled candidate nodes are inactive in
-        # that scenario (the reference regenerates them per run)
-        self._ds_target = np.full(len(pods), -1, dtype=np.int64)
-        name_to_idx = self.oracle.node_index
-        for p_i, pod in enumerate(pods):
-            target = _daemonset_target(pod)
-            if target is not None and target in name_to_idx:
-                self._ds_target[p_i] = name_to_idx[target]
+        with phase("sweep/index"):
+            self.had_node_name = np.array(
+                [bool((p.get("spec") or {}).get("nodeName")) for p in pods], dtype=bool
+            )
+            # daemonset pods of disabled candidate nodes are inactive in
+            # that scenario (the reference regenerates them per run)
+            self._ds_target = np.full(len(pods), -1, dtype=np.int64)
+            name_to_idx = self.oracle.node_index
+            for p_i, pod in enumerate(pods):
+                target = _daemonset_target(pod)
+                if target is not None and target in name_to_idx:
+                    self._ds_target[p_i] = name_to_idx[target]
         self._probe_jit = None
         self._many_jit = None
         # process-wide mesh (parallel/mesh.py configure/current_mesh,
@@ -281,14 +283,15 @@ class CapacitySweep:
 
         assert INACTIVE == scan_ops.INACTIVE == pallas_scan.INACTIVE
 
-        self._pallas_plan = (
-            pallas_scan.build_plan(
-                self.cluster_enc, self.batch, self.dyn, self.features,
-                weights=self.features.weights,
+        with phase("sweep/kernel-plan"):
+            self._pallas_plan = (
+                pallas_scan.build_plan(
+                    self.cluster_enc, self.batch, self.dyn, self.features,
+                    weights=self.features.weights,
+                )
+                if pallas_scan.should_use()
+                else None
             )
-            if pallas_scan.should_use()
-            else None
-        )
         from ..utils.trace import GLOBAL
 
         GLOBAL.note(
@@ -866,49 +869,52 @@ class CapacitySweep:
         unschedulable (sum of requests exceeds sum of allocatable) or
         violates a cap, so the scheduling search can start here. Purely
         arithmetic — no scan."""
-        b, c_enc, d = self.batch, self.cluster_enc, self.dyn
-        cls = b.class_of_pod
-        req = {
-            "mcpu": b.req_mcpu[cls].astype(np.int64),
-            "mem": b.req_mem[cls].astype(np.int64),
-            "eph": b.req_eph[cls].astype(np.int64),
-            "pods": np.ones(len(self.pods), dtype=np.int64),
-            "vg": b.lvm_sizes[cls].sum(axis=1).astype(np.int64),
-        }
-        alloc = {
-            "mcpu": c_enc.alloc_mcpu,
-            "mem": c_enc.alloc_mem,
-            "eph": c_enc.alloc_eph,
-            "pods": c_enc.alloc_pods,
-            "vg": c_enc.vg_cap.sum(axis=1),
-        }
-        base_used = {
-            "mcpu": int(d.used_mcpu.sum()),
-            "mem": int(d.used_mem.sum()),
-            "eph": int(d.used_eph.sum()),
-            "pods": int(d.pod_cnt.sum()),
-            "vg": int(d.vg_used.sum()),
-        }
-        for count in range(0, self.max_count + 1):
-            valid = self.node_valid(count)
-            active = self.pod_active(valid)
-            ok = True
-            for r in ("mcpu", "mem", "eph", "pods"):
-                if base_used[r] + int(req[r][active].sum()) > int(alloc[r][valid].sum()):
-                    ok = False
-                    break
-            if ok:
-                for r, cap in (("mcpu", max_cpu), ("mem", max_mem), ("vg", max_vg)):
-                    total_alloc = int(alloc[r][valid].sum())
-                    if total_alloc == 0:
-                        continue
-                    used = base_used[r] + int(req[r][active].sum())
-                    if int(used / total_alloc * 100) > cap:
+        from ..utils.trace import phase
+
+        with phase("sweep/lower-bound"):
+            b, c_enc, d = self.batch, self.cluster_enc, self.dyn
+            cls = b.class_of_pod
+            req = {
+                "mcpu": b.req_mcpu[cls].astype(np.int64),
+                "mem": b.req_mem[cls].astype(np.int64),
+                "eph": b.req_eph[cls].astype(np.int64),
+                "pods": np.ones(len(self.pods), dtype=np.int64),
+                "vg": b.lvm_sizes[cls].sum(axis=1).astype(np.int64),
+            }
+            alloc = {
+                "mcpu": c_enc.alloc_mcpu,
+                "mem": c_enc.alloc_mem,
+                "eph": c_enc.alloc_eph,
+                "pods": c_enc.alloc_pods,
+                "vg": c_enc.vg_cap.sum(axis=1),
+            }
+            base_used = {
+                "mcpu": int(d.used_mcpu.sum()),
+                "mem": int(d.used_mem.sum()),
+                "eph": int(d.used_eph.sum()),
+                "pods": int(d.pod_cnt.sum()),
+                "vg": int(d.vg_used.sum()),
+            }
+            for count in range(0, self.max_count + 1):
+                valid = self.node_valid(count)
+                active = self.pod_active(valid)
+                ok = True
+                for r in ("mcpu", "mem", "eph", "pods"):
+                    if base_used[r] + int(req[r][active].sum()) > int(alloc[r][valid].sum()):
                         ok = False
                         break
-            if ok:
-                return count
-        return self.max_count
+                if ok:
+                    for r, cap in (("mcpu", max_cpu), ("mem", max_mem), ("vg", max_vg)):
+                        total_alloc = int(alloc[r][valid].sum())
+                        if total_alloc == 0:
+                            continue
+                        used = base_used[r] + int(req[r][active].sum())
+                        if int(used / total_alloc * 100) > cap:
+                            ok = False
+                            break
+                if ok:
+                    return count
+            return self.max_count
 
     # -- minimal-count search ----------------------------------------------
 

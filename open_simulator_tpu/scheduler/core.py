@@ -593,7 +593,7 @@ class Simulator:
         import numpy as np
 
         from .engine import TpuEngine
-        from ..utils.trace import profiled
+        from ..utils.trace import phase
 
         p = len(pods)
         if self._engine is None or self._engine.oracle is not self.oracle:
@@ -680,7 +680,7 @@ class Simulator:
             eng.rewind_sample_rng(int(pos_of[escape_at]))
         failed: List[UnscheduledPod] = []
         stop = p if escape_at is None else escape_at
-        with profiled("engine/replay"):
+        with phase("engine/replay"):
             self._replay_window(pods, placements, start, stop, prios, failed)
         return failed, escape_at
 
@@ -824,7 +824,10 @@ def simulate(
     # strong refs to pod/node sub-objects.
     import gc
 
-    cluster = cluster.copy()
+    from ..utils.trace import phase
+
+    with phase("sim/copy"):
+        cluster = cluster.copy()
     failed: List[UnscheduledPod] = []
     preemptions: List[PreemptionEvent] = []
     # a run allocates hundreds of thousands of short-lived dicts (pod
@@ -842,7 +845,8 @@ def simulate(
         # intermediate node_status snapshots are discarded here (only
         # the final one is returned), so skip building them — an
         # N-node list copy per app otherwise
-        result = sim.run_cluster(cluster, build_status=False)
+        with phase("sim/run-cluster"):
+            result = sim.run_cluster(cluster, build_status=False)
         failed.extend(result.unscheduled_pods)
         preemptions.extend(result.preemptions)
         for app in apps:
@@ -851,9 +855,11 @@ def simulate(
             result = sim.schedule_app(app, build_status=False)
             failed.extend(result.unscheduled_pods)
             preemptions.extend(result.preemptions)
+        with phase("sim/node-status"):
+            node_status = sim.node_status()
         return SimulateResult(
             unscheduled_pods=failed,
-            node_status=sim.node_status(),
+            node_status=node_status,
             preemptions=preemptions,
         )
     finally:

@@ -110,23 +110,25 @@ class TpuEngine:
 
         oracle = self.oracle
         with phase("engine/encode"):
-            cluster = self.cluster_static()
-            batch = encode_batch(oracle, cluster, pods, groups=groups)
-            from .oracle import ClassCommitCache, simple_commit_mask
+            with phase("engine/encode-cluster"):
+                cluster = self.cluster_static()
+            with phase("engine/encode-batch"):
+                batch = encode_batch(oracle, cluster, pods, groups=groups)
+                from .oracle import ClassCommitCache, simple_commit_mask
 
-            self._batch = batch
-            self._batch_pods = pods
-            self._last_class_of = np.asarray(batch.class_of_pod)
-            self._last_simple = simple_commit_mask(batch, bool(oracle.extenders))
-            self._class_commit_info = ClassCommitCache()
-            self._bulk_tbl = None
-            self._scan_static = None
-            sample = getattr(oracle, "select_host", "first-max") == "sample"
-            self._features = features_of_batch(
-                cluster, batch,
-                weights=getattr(oracle, "score_weights", None),
-                sample=sample,
-            )
+                self._batch = batch
+                self._batch_pods = pods
+                self._last_class_of = np.asarray(batch.class_of_pod)
+                self._last_simple = simple_commit_mask(batch, bool(oracle.extenders))
+                self._class_commit_info = ClassCommitCache()
+                self._bulk_tbl = None
+                self._scan_static = None
+                sample = getattr(oracle, "select_host", "first-max") == "sample"
+                self._features = features_of_batch(
+                    cluster, batch,
+                    weights=getattr(oracle, "score_weights", None),
+                    sample=sample,
+                )
 
     def scan_active(
         self, active: np.ndarray, valid: Optional[np.ndarray] = None
@@ -147,7 +149,7 @@ class TpuEngine:
         from ..ops import pallas_scan
         from ..ops import scan as scan_ops
         from ..ops.encode import to_scan_static, to_scan_state
-        from ..utils.trace import GLOBAL, phase, profiled
+        from ..utils.trace import GLOBAL, phase
 
         oracle = self.oracle
         batch = self._batch
@@ -159,22 +161,25 @@ class TpuEngine:
                 if valid is None
                 else np.asarray(valid, bool)
             )
-            dyn = encode_dynamic(oracle, cluster)
-            plan = (
-                pallas_scan.build_plan(
-                    cluster, batch, dyn, self._features,
-                    weights=self._features.weights,
+            with phase("engine/encode-state"):
+                dyn = encode_dynamic(oracle, cluster)
+            with phase("engine/kernel-plan"):
+                plan = (
+                    pallas_scan.build_plan(
+                        cluster, batch, dyn, self._features,
+                        weights=self._features.weights,
+                    )
+                    if pallas_scan.should_use()
+                    else None
                 )
-                if pallas_scan.should_use()
-                else None
-            )
             if plan is None:
-                # the scan static survives masked rounds; only a
-                # ClusterStatic rebuild (GPU alloc epoch) invalidates it
-                if self._scan_static is None or self._scan_static_cluster is not cluster:
-                    self._scan_static = to_scan_static(cluster, batch)
-                    self._scan_static_cluster = cluster
-                init = to_scan_state(dyn, batch)
+                with phase("engine/encode-state"):
+                    # the scan static survives masked rounds; only a
+                    # ClusterStatic rebuild (GPU alloc epoch) invalidates it
+                    if self._scan_static is None or self._scan_static_cluster is not cluster:
+                        self._scan_static = to_scan_static(cluster, batch)
+                        self._scan_static_cluster = cluster
+                    init = to_scan_state(dyn, batch)
                 if sample:
                     # the scan consumes the oracle's Go RNG stream: hand
                     # its 607-output history in via the carry, and (after
@@ -219,20 +224,28 @@ class TpuEngine:
         if plan is not None:
             # fused single-kernel fast path; bit-identical placements
             # (tests/test_pallas_scan.py)
-            with profiled("engine/scan"):
-                out, _final = pallas_scan.run_scan_pallas(
+            from ..obs import profile
+
+            with phase("engine/scan"):
+                out_d = pallas_scan.run_scan_pallas(
                     plan,
                     batch.class_of_pod,
                     np.asarray(active, bool),
                     node_valid,
                     pinned=batch.pinned_node,
+                    defer=True,
+                )
+                fetched = np.asarray(out_d)  # blocks on device completion
+                profile.record_d2h(fetched.nbytes)
+                out, _final = pallas_scan.decode_scan_output(
+                    plan, fetched, len(batch.class_of_pod)
                 )
             return np.asarray(out)
         if mesh_route is not None:
             from ..parallel import mesh as mesh_mod
 
             try:
-                with profiled("engine/scan"):
+                with phase("engine/scan"):
                     out, *_stats = mesh_mod.run_node_sharded(
                         mesh_route,
                         self._scan_static,
@@ -252,7 +265,7 @@ class TpuEngine:
                 ):
                     raise
                 self._mesh_retired = True
-        with profiled("engine/scan"):
+        with phase("engine/scan"):
             placements, final_state = scan_ops.run_scan_masked(
                 self._scan_static,
                 init,
@@ -302,7 +315,7 @@ class TpuEngine:
         import jax.numpy as jnp
 
         from ..ops.encode import to_scan_static, to_scan_state
-        from ..utils.trace import phase, profiled
+        from ..utils.trace import phase
 
         if bool(getattr(self._features, "sample", False)):
             # the Go-RNG stream is a single serial sequence; scenario
@@ -313,11 +326,12 @@ class TpuEngine:
         batch = self._batch
         with phase("engine/encode"):
             cluster = self.cluster_static()
-            dyn = encode_dynamic(self.oracle, cluster)
-            if self._scan_static is None or self._scan_static_cluster is not cluster:
-                self._scan_static = to_scan_static(cluster, batch)
-                self._scan_static_cluster = cluster
-            init = to_scan_state(dyn, batch)
+            with phase("engine/encode-state"):
+                dyn = encode_dynamic(self.oracle, cluster)
+                if self._scan_static is None or self._scan_static_cluster is not cluster:
+                    self._scan_static = to_scan_static(cluster, batch)
+                    self._scan_static_cluster = cluster
+                init = to_scan_state(dyn, batch)
         actives_arr = np.asarray(actives, bool)
         # scenario-axis sharding: coalesced request rows are
         # independent, so a configured mesh splits them across devices
@@ -341,7 +355,7 @@ class TpuEngine:
                 (actives_s,), rows = mesh_mod.shard_scenario_rows(
                     mesh_route, [actives_arr]
                 )
-                with profiled("engine/scan"):
+                with phase("engine/scan"):
                     out = _scenario_scan_jit()(
                         self._scan_static,
                         init,
@@ -363,7 +377,7 @@ class TpuEngine:
                 self._mesh_retired = True
                 out = None
         if out is None:
-            with profiled("engine/scan"):
+            with phase("engine/scan"):
                 out = _scenario_scan_jit()(
                     self._scan_static,
                     init,

@@ -5,15 +5,17 @@ vendored scheduler metrics that are never scraped). Here per-phase
 wall-clock is first-class: every scheduling run records named phases
 (encode / compile+scan / decode / replay / report ...) into a
 process-local trace that can be printed as JSON (`simon apply
---trace`), and an optional JAX profiler capture can wrap any phase for
-TPU-level analysis (`SIMON_PROFILE_DIR=... ` -> TensorBoard trace).
+--trace`). Every phase is also a host annotation of a running
+`jax.profiler` capture, on the device ops' clock: `--profile-dir DIR`
+takes one capture of the whole command (cli._obs_begin), and each idle
+gap of the device in it falls inside the phases open over it.
 """
 
 from __future__ import annotations
 
 import json
 import math
-import os
+import sys
 import threading
 import time
 from contextlib import contextmanager
@@ -130,18 +132,27 @@ GLOBAL = Trace()
 
 @contextmanager
 def phase(name: str, trace: Optional[Trace] = None):
-    """Record wall-clock of the enclosed block under `name`. When the
-    flight recorder is on (--trace-out), the block is also recorded as
-    a span nested under the caller's current span — phases called
-    inside phases nest automatically via the contextvar parent."""
+    """Record wall-clock of the enclosed block under `name`. The block
+    is also a `jax.profiler.TraceAnnotation` (recorded only while a
+    capture runs) and, when the flight recorder is on (--trace-out), a
+    span nested under the caller's current span — phases called inside
+    phases nest automatically via the contextvar parent."""
     span_cm = _SPANS.span(name, kind="phase") if _SPANS.enabled else None
     if span_cm is not None:
         span_cm.__enter__()
+    # jax is looked up, never imported: this module loads before jax,
+    # and no capture can run before the program has imported it
+    jax = sys.modules.get("jax")
+    note = jax.profiler.TraceAnnotation(name) if jax is not None else None
+    if note is not None:
+        note.__enter__()
     t0 = time.perf_counter()
     try:
         yield
     finally:
         (trace or GLOBAL).add(name, time.perf_counter() - t0)
+        if note is not None:
+            note.__exit__(None, None, None)
         if span_cm is not None:
             span_cm.__exit__(None, None, None)
 
@@ -299,22 +310,3 @@ def _count_dropped_spans(n: int = 1) -> None:
 
 
 _set_span_drop_hook(_count_dropped_spans)
-
-
-@contextmanager
-def profiled(name: str, trace: Optional[Trace] = None):
-    """phase() + a JAX profiler capture when SIMON_PROFILE_DIR is set.
-
-    The capture lands in $SIMON_PROFILE_DIR/<name>/ and is viewable in
-    TensorBoard / Perfetto (jax.profiler.trace)."""
-    profile_dir = os.environ.get("SIMON_PROFILE_DIR")
-    if not profile_dir:
-        with phase(name, trace):
-            yield
-        return
-    import jax
-
-    target = os.path.join(profile_dir, name.replace("/", "_"))
-    with phase(name, trace):
-        with jax.profiler.trace(target):
-            yield

@@ -37,10 +37,9 @@ def test_append_note_accumulates_and_caps_by_entry_count():
     assert tr.appended == {} and tr.notes == {}
 
 
-def test_engine_records_phases():
-    GLOBAL.reset()
+def _web_app(replicas=3):
     from open_simulator_tpu.models.decode import ResourceTypes
-    from open_simulator_tpu.scheduler.core import AppResource, simulate
+    from open_simulator_tpu.scheduler.core import AppResource
     from open_simulator_tpu.testing import make_fake_node
 
     cluster = ResourceTypes()
@@ -51,7 +50,7 @@ def test_engine_records_phases():
             "kind": "Deployment",
             "metadata": {"name": "web", "namespace": "d"},
             "spec": {
-                "replicas": 3,
+                "replicas": replicas,
                 "template": {
                     "spec": {
                         "containers": [
@@ -66,11 +65,139 @@ def test_engine_records_phases():
             },
         }
     ]
-    out = simulate(cluster, [AppResource("web", res)], engine="tpu")
-    assert not out.unscheduled_pods
-    names = {p["name"] for p in GLOBAL.as_dict()["phases"]}
-    assert {"engine/encode", "engine/scan"} <= names
+    return cluster, [AppResource("web", res)]
+
+
+def _phase_names():
+    return {p["name"] for p in GLOBAL.as_dict()["phases"]}
+
+
+def _host_event_names(log_dir):
+    """Names of the host events in the one capture under `log_dir`."""
+    from jax.profiler import ProfileData
+
+    found = list(log_dir.rglob("*.xplane.pb"))
+    assert len(found) == 1, found
+    pd = ProfileData.from_file(str(found[0]))
+    return {
+        e.name
+        for plane in pd.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    }
+
+
+def test_engine_records_phases():
+    from open_simulator_tpu.scheduler.core import simulate
+
     GLOBAL.reset()
+    cluster, apps = _web_app()
+    out = simulate(cluster, apps, engine="tpu")
+    assert not out.unscheduled_pods
+    assert {"engine/encode", "engine/scan"} <= _phase_names()
+    GLOBAL.reset()
+
+
+def test_phase_annotates_profiler_capture(tmp_path):
+    # a phase is a host annotation of a running capture, on the same
+    # clock as the device ops: idle gaps can be put down to it
+    import jax
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with phase("x/y", Trace()):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    assert "x/y" in _host_event_names(tmp_path)
+
+
+def test_trace_imports_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; sys.modules['jax'] = None\n"
+        "from open_simulator_tpu.utils.trace import GLOBAL, phase\n"
+        "with phase('a/b'): pass\n"
+        "assert GLOBAL.phase_seconds('a/b') >= 0 and 'a/b' in GLOBAL.phases\n"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=60)
+
+
+def test_simulate_records_cluster_and_encode_spans():
+    from open_simulator_tpu.scheduler.core import simulate
+
+    GLOBAL.reset()
+    cluster, apps = _web_app()
+    simulate(cluster, apps, engine="tpu")
+    assert {
+        "sim/copy", "sim/run-cluster", "sim/node-status",
+        "engine/encode", "engine/encode-cluster", "engine/encode-batch",
+        "engine/encode-state", "engine/kernel-plan",
+    } <= _phase_names()
+    GLOBAL.reset()
+
+
+def test_probe_plan_records_lower_bound_and_finalize_spans():
+    import gc
+
+    from open_simulator_tpu.apply.applier import probe_plan
+    from open_simulator_tpu.testing import make_fake_node
+
+    assert gc.isenabled()
+    GLOBAL.reset()
+    cluster, apps = _web_app(replicas=30)
+    res = probe_plan(cluster, apps, make_fake_node("tpl", cpu="8", memory="16Gi"), max_count=4)
+    assert res.success and res.new_node_count == 1
+    names = _phase_names()
+    assert {
+        "sweep/pad", "sweep/index", "sweep/kernel-plan", "sweep/lower-bound",
+        "apply/clear-memos", "apply/gc",
+    } <= names
+    assert "apply/lower-bound" not in names
+    assert gc.isenabled()
+    GLOBAL.reset()
+
+
+def test_fused_scan_counts_fetched_bytes(monkeypatch):
+    # the fused kernel's one fetch is a device-to-host transfer like
+    # the XLA scan's, and the kernel keeps its name in a trace
+    from open_simulator_tpu.ops import pallas_scan as ps
+    from open_simulator_tpu.scheduler.core import simulate
+    from open_simulator_tpu.utils.trace import COUNTERS
+
+    monkeypatch.setattr(ps, "FORCE_ENABLE", True)
+    GLOBAL.reset()
+    cluster, apps = _web_app()
+    before = COUNTERS.get("device_transfer_d2h_bytes_total")
+    out = simulate(cluster, apps, engine="tpu")
+    assert not out.unscheduled_pods
+    assert GLOBAL.notes["batch-kernel"].startswith("pallas")
+    # placements (8 x 128 lanes) and six node-state rows, int32
+    assert COUNTERS.get("device_transfer_d2h_bytes_total") - before >= 4 * 7 * 8 * 128
+    assert {c.fn.__name__ for c in ps._COMPILED_CACHE.values()} == {"pod_scan_fused"}
+    GLOBAL.reset()
+
+
+def test_profile_dir_writes_one_capture_with_phases(tmp_path, monkeypatch):
+    import os
+
+    from open_simulator_tpu.cli import main
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.chdir(repo)
+    monkeypatch.delenv("SIMON_PROFILE_DIR", raising=False)
+    prof = tmp_path / "prof"
+    code = main([
+        "apply", "-f", "example/simon-config.yaml", "--format", "json",
+        "--profile-dir", str(prof),
+    ])
+    assert code == 0
+    assert "SIMON_PROFILE_DIR" not in os.environ
+    names = _host_event_names(prof)
+    assert {"sweep/lower-bound", "sweep/probe", "apply/replay", "apply/gc"} <= names
 
 
 def test_kernel_fallback_names_reason():

@@ -8,11 +8,13 @@ collapses into dense arrays:
   pod slots) and a generic `[R, N]` allocatable matrix for the Simon
   max-share score (plugin/simon.go:44-67)
 - per-pod-CLASS static matrices `[U, N]`: everything that does not
-  depend on placement state — taint/affinity/nodename/unschedulable
+  depend on placement state — taint/affinity/unschedulable
   feasibility, preferred-node-affinity raw scores, PreferNoSchedule
   intolerable-taint counts, NodePreferAvoidPods, ImageLocality, Simon
-  raw shares. Pods expanded from the same workload share a class, so
-  the O(pods x nodes) host work shrinks to O(classes x nodes).
+  raw shares. Pods expanded from the same workload share a class, and
+  so do bound pods of one template on different nodes (the nodeName
+  pin is per-pod data, `PodBatch.pinned_node`), so the O(pods x nodes)
+  host work shrinks to O(classes x nodes).
 - a small host-port vocabulary with a pairwise conflict matrix
   (wildcard-IP semantics of HostPortInfo.CheckConflict)
 - per-device GPU memory state for the open-gpu-share plugin
@@ -33,6 +35,7 @@ from ..models import labels as lbl
 from ..models import requests as req
 from ..models import storage as stor
 from ..utils.memo import IdentityMemo, register_cache
+from ..utils.trace import COUNTERS
 from .profiles import freeze as _freeze
 from .profiles import node_profiles_cached as _shared_node_profiles
 from .profiles import uses_match_fields as _uses_match_fields
@@ -260,11 +263,14 @@ def _class_key(pod: dict):
             break
     # content-based equality is preserved: the interned prefix compares
     # by content (identical content from distinct templates interns to
-    # one object), per-pod cheap fields ride alongside
+    # one object), per-pod cheap fields ride alongside. metadata.name
+    # and spec.nodeName are not class content: the nodeName pin is
+    # per-pod data (PodBatch.pinned_node) and every consumer reads it
+    # from the pod's own row, so bound pods of one template on N nodes
+    # are one class, not N
     return (
         _class_prefix(spec, meta.get("labels")),
         meta.get("namespace"),
-        spec.get("nodeName"),
         spec.get("hostNetwork"),
         anno.get(stor.GPU_MEM_ANNO),
         anno.get(stor.GPU_COUNT_ANNO),
@@ -513,7 +519,12 @@ def encode_batch(
     content-identical except metadata.name, so the class key, host
     ports, and pin target resolve once per GROUP and broadcast to pods
     by numpy indexing — the class-dedup loop drops from O(pods) dict
-    work to O(groups)."""
+    work to O(groups).
+
+    Classes are built from pod content alone (`_class_key`): the
+    nodeName pin is per-pod data in `pinned_node`, never class content,
+    so bound and loose pods of one template share a class and the
+    [U, N] tables grow with templates, not with bound pods."""
     # port vocabulary over batch + existing usage
     vocab: List[tuple] = []
     seen = set()
@@ -576,6 +587,8 @@ def encode_batch(
     u = len(class_pods)
     n = cluster.n
     s = len(cluster.scalar_names)
+    COUNTERS.inc("encode_pod_classes_total", u)
+    COUNTERS.inc("encode_pinned_pods_total", int((pinned >= 0).sum()))
 
     req_mcpu = np.zeros(u, dtype=np.int64)
     req_mem = np.zeros(u, dtype=np.int64)

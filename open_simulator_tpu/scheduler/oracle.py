@@ -276,7 +276,9 @@ def simple_commit_mask(batch, has_extenders: bool):
 class ClassCommitCache:
     """(request summary, host-port tuple) per batch-scoped pod class —
     class members share request/port content by class-key construction
-    (ops/encode.py:_class_key), so the walk runs once per class."""
+    (ops/encode.py:_class_key), so the walk runs once per class. The
+    pod's node is never class content (members may be pinned to
+    different nodes, or loose): it always comes from the caller's `ns`."""
 
     __slots__ = ("_info",)
 
@@ -1688,7 +1690,9 @@ class Oracle:
         host-port tuple) already in hand — the capacity replay passes
         per-CLASS values so the 100k-pod walk does only aggregate
         arithmetic per pod (class members share request/port content by
-        class-key construction, ops/encode.py:_class_key)."""
+        class-key construction, ops/encode.py:_class_key; the node is
+        the caller's `ns`, since a spec.nodeName pin is per-pod data and
+        not class content)."""
         ns.pods.append(pod)
         ns.req_mcpu += s.mcpu
         ns.req_mem += s.mem

@@ -542,6 +542,19 @@ def _observatory_lines(snap: dict) -> List[str]:
         "full one.",
         counts.get("incremental_fallbacks_total", 0),
     )
+    # -- batch encoding (ops/encode.py encode_batch)
+    metric(
+        "simon_encode_pod_classes_total", "counter",
+        "Pod classes built by batch encodes; per-class host work is "
+        "O(classes x nodes).",
+        counts.get("encode_pod_classes_total", 0),
+    )
+    metric(
+        "simon_encode_pinned_pods_total", "counter",
+        "Pods encoded with a spec.nodeName pin (per-pod data, never "
+        "class content).",
+        counts.get("encode_pinned_pods_total", 0),
+    )
     metric(
         "simon_jax_cost_flops_dispatched_total", "counter",
         "FLOPs itemized across every AOT dispatch.",

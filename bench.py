@@ -1992,7 +1992,10 @@ def run_tier_stress(n_nodes=128, n_zero=1000) -> dict:
     finishes on the serial oracle. Measures the cost of the
     MAX_SCAN_ESCAPES ladder itself — rounds, escapes, serial-tail
     size — which the unit tests only pin semantically
-    (tests/test_preemption.py, tests/test_tiered_scan.py)."""
+    (tests/test_preemption.py, tests/test_tiered_scan.py). A
+    PodDisruptionBudget selects every victim: that puts them out of
+    the device dry run's scope (ops/preempt.py), which would otherwise
+    preempt for every preemptor inside the one scan."""
     from open_simulator_tpu.models.decode import ResourceTypes
     from open_simulator_tpu.scheduler.core import (
         MAX_SCAN_ESCAPES,
@@ -2009,7 +2012,7 @@ def run_tier_stress(n_nodes=128, n_zero=1000) -> dict:
                 "metadata": {
                     "name": f"victim-{i:04d}",
                     "namespace": "bench",
-                    "labels": {},
+                    "labels": {"role": "victim"},
                 },
                 "spec": {
                     "nodeName": f"tier-node-{i:04d}",
@@ -2076,6 +2079,13 @@ def run_tier_stress(n_nodes=128, n_zero=1000) -> dict:
     cluster = ResourceTypes()
     cluster.nodes = nodes
     cluster.pods = victims
+    cluster.pod_disruption_budgets = [
+        {
+            "kind": "PodDisruptionBudget",
+            "metadata": {"name": "victims", "namespace": "bench"},
+            "spec": {"selector": {"matchLabels": {"role": "victim"}}},
+        }
+    ]
     res = ResourceTypes()
     res.pods = pods
     apps = [AppResource("bench", res)]

@@ -29,6 +29,10 @@ Scope (automatic fallback to the XLA scan otherwise):
   HBM buffer and each pod step DMA-gathers only its class's rows
   (StreamTermsPlan docstring) — the ~12.3k-node cliff becomes a
   bandwidth slope (50k nodes measured),
+- DefaultPreemption's dry run IS in scope (ops/preempt.py): the
+  committed pods ride as (F*K, R, C) slot tiles in VMEM scratch, and a
+  pod that fails every node runs the dry run, pick and eviction in the
+  same step (dry_run below), up to _PRE_MAX_K slots a node,
 - all quantities must fit exactness-preserving int32 encodings:
   memory/ephemeral values are divided by their collective GCD
   (floor-division identities keep every score and fit comparison
@@ -199,6 +203,26 @@ class TermsPlan(NamedTuple):
     w_h2: np.ndarray
 
 
+# the device dry run's slot fields (ops/preempt.py), in table order
+_PRE_FIELDS = ("valid", "hard", "prio", "seq", "mcpu", "mem", "eph", "nz_mcpu", "nz_mem")
+# slots per node the kernel's unrolled dry run holds (K^2 selects per
+# field); a batch needing more runs its dry run on the XLA scan
+_PRE_MAX_K = 16
+
+
+class PreemptPlan(NamedTuple):
+    """DefaultPreemption's dry run in the kernel (_kernel_dry_run): the
+    committed pods as (F*K, R, C) int32 tiles, field f of slot k at
+    f*K + k (_PRE_FIELDS order, memory in the plan's scaled units), the
+    per-pod (3, Pr, C) rows priority / dry run allowed / out of scope
+    once committed, and the next commit sequence."""
+
+    k: int
+    table0: np.ndarray  # (F*K, R, C) init slots (ANY)
+    pod: np.ndarray  # (3, Pr, C) VMEM
+    meta: np.ndarray  # (1,) SMEM next commit sequence
+
+
 class PallasPlan(NamedTuple):
     """Host-side (numpy) arrays prepared for the kernel, all padded to
     (R, 128) node tiles / int32."""
@@ -269,6 +293,8 @@ class PallasPlan(NamedTuple):
     # precomputed SMEM tables indexed by (class, distinct node storage
     # config, in-kernel assignment pattern) — see _build_storage
     store: Optional["StorePlan"] = None
+    # the device dry run (ops/preempt.py); None = no preemption
+    pre: Optional[PreemptPlan] = None
 
 
 def _pad_nodes(vec: np.ndarray, r: int, fill=0) -> np.ndarray:
@@ -818,10 +844,15 @@ STREAM_FORCE: Optional[bool] = None
 
 
 def build_plan(cluster, batch, dyn, features, weights=None,
-               allow_terms: Optional[bool] = None) -> Optional[PallasPlan]:
+               allow_terms: Optional[bool] = None,
+               preempt=None) -> Optional[PallasPlan]:
     """Build a kernel plan from the (numpy) ClusterStatic + PodBatch +
     DynamicState, or None when the batch is outside the fast path's
-    scope."""
+    scope. With features.preempt, `preempt` is (ops/preempt.table_np
+    slots, next commit sequence, per-pod priority, dry run allowed, out
+    of scope once committed)."""
+    if getattr(features, "preempt", False) and preempt is None:
+        return _reject("preemption dry run without its slots")
     if features.custom:
         return _reject("custom-plugin machinery (XLA scan carries it)")
     if getattr(features, "sample", False):
@@ -860,9 +891,12 @@ def build_plan(cluster, batch, dyn, features, weights=None,
     init_nz_mem = a(dyn.nz_mem, dtype=np.int64)
     init_pod_cnt = a(dyn.pod_cnt, dtype=np.int64)
 
-    s_mem = _gcd_scale(alloc_mem, req_mem, init_used_mem)
-    s_eph = _gcd_scale(alloc_eph, req_eph, init_used_eph)
-    s_nzmem = _gcd_scale(alloc_mem, nz_mem, init_nz_mem)
+    # a preemption releases single pods' requests: the scales divide
+    # every committed pod's too
+    pre_tab = preempt[0] if preempt is not None else {}
+    s_mem = _gcd_scale(alloc_mem, req_mem, init_used_mem, pre_tab.get("mem", ()))
+    s_eph = _gcd_scale(alloc_eph, req_eph, init_used_eph, pre_tab.get("eph", ()))
+    s_nzmem = _gcd_scale(alloc_mem, nz_mem, init_nz_mem, pre_tab.get("nz_mem", ()))
 
     simon_raw = a(batch.simon_raw, dtype=np.int64)
     nodeaff_raw = a(batch.nodeaff_raw, dtype=np.int64)
@@ -899,19 +933,23 @@ def build_plan(cluster, batch, dyn, features, weights=None,
 
     if features.pins:
         # forced pin commits bypass the feasibility gate, so per-node
-        # usage is no longer bounded by alloc: bound the worst case
-        # (all pinned pods on one node) against the f32/int32 guards
-        pin_mask = a(batch.pinned_node) >= 0
+        # usage is no longer bounded by alloc: bound each node's usage
+        # with every pod pinned to it against the f32/int32 guards
+        pinned_node = a(batch.pinned_node)
+        pin_mask = pinned_node >= 0
+        pin_at = pinned_node[pin_mask]
         pin_cls = a(batch.class_of_pod)[pin_mask]
-        pin_c = int(req_mcpu[pin_cls].sum())
-        pin_m = int((req_mem // s_mem)[pin_cls].sum())
-        pin_nzc = int(nz_mcpu[pin_cls].sum())
-        pin_nzm = int((nz_mem // s_nzmem)[pin_cls].sum())
+
+        def with_pins(init, per_class):
+            used = np.array(init, dtype=np.int64)
+            np.add.at(used, pin_at, per_class[pin_cls])
+            return int(used.max(initial=0))
+
         worst = max(
-            int(init_used_mcpu.max(initial=0)) + pin_c,
-            int((init_used_mem // s_mem).max(initial=0)) + pin_m,
-            int(init_nz_mcpu.max(initial=0)) + pin_nzc,
-            int((init_nz_mem // s_nzmem).max(initial=0)) + pin_nzm,
+            with_pins(init_used_mcpu, req_mcpu),
+            with_pins(init_used_mem // s_mem, req_mem // s_mem),
+            with_pins(init_nz_mcpu, nz_mcpu),
+            with_pins(init_nz_mem // s_nzmem, nz_mem // s_nzmem),
         )
         if worst >= 2**24:
             return _reject("pinned-pod worst-case usage exceeds f32 exactness")
@@ -1022,6 +1060,13 @@ def build_plan(cluster, batch, dyn, features, weights=None,
             return None
         terms, hk_map = built
 
+    pre = None
+    if preempt is not None:
+        pre = _build_preempt(preempt, r, int(a(batch.class_of_pod).shape[0]),
+                             s_mem, s_eph, s_nzmem)
+        if pre is None:
+            return None
+
     class_scalars = np.zeros((u, 8), dtype=np.int32)
     class_scalars[:, 0] = req_mcpu
     class_scalars[:, 1] = req_mem // s_mem
@@ -1096,6 +1141,7 @@ def build_plan(cluster, batch, dyn, features, weights=None,
         gpu_mem_u=gpu_mem_u,
         gpu_cnt_u=gpu_cnt_u,
         store=store,
+        pre=pre,
     )
 
     # VMEM budget (~16MB/core): count the PERSISTENT (R, C) tiles
@@ -1119,6 +1165,7 @@ def build_plan(cluster, batch, dyn, features, weights=None,
             if store is not None
             else 0
         )
+        + (len(_PRE_FIELDS) * pre.k if pre is not None else 0)  # slot scratch
     )
     tiles = base_tiles
     if terms is not None:
@@ -1167,6 +1214,45 @@ def build_plan(cluster, batch, dyn, features, weights=None,
     global _LAST_REJECT
     _LAST_REJECT = None
     return plan
+
+
+def _build_preempt(preempt, r: int, p_total: int, s_mem: int, s_eph: int,
+                   s_nzmem: int) -> Optional[PreemptPlan]:
+    """The dry run's int32 inputs (PreemptPlan), or None (recorded) when
+    they leave the kernel's scope: more than _PRE_MAX_K slots, or
+    priorities, sequences or requests past int32 exactness. Priority
+    sums run over up to K victims."""
+    table, seq_next, prio, ok, hard = preempt
+    k = int(table["valid"].shape[0])
+    if k > _PRE_MAX_K:
+        return _reject(f"preemption: {k} pod slots per node > {_PRE_MAX_K}")
+    valid = np.asarray(table["valid"], bool)
+    prio_all = np.concatenate([np.asarray(prio, np.int64).ravel(),
+                               np.asarray(table["prio"], np.int64)[valid]])
+    scaled = {
+        "mem": np.asarray(table["mem"], np.int64) // s_mem,
+        "eph": np.asarray(table["eph"], np.int64) // s_eph,
+        "nz_mem": np.asarray(table["nz_mem"], np.int64) // s_nzmem,
+    }
+    if (
+        np.abs(prio_all).max(initial=0) * (k + 1) >= 2**31
+        or seq_next + p_total >= 2**31
+        or max(int(np.asarray(scaled.get(f, table[f])).max(initial=0))
+               for f in ("mcpu", "mem", "eph", "nz_mcpu", "nz_mem")) > _MAX_SCALED
+    ):
+        return _reject("preemption: priorities or requests exceed int32 exactness")
+    rows = [
+        np.asarray(scaled.get(f, table[f])).astype(np.int64) for f in _PRE_FIELDS
+    ]
+    table0 = np.concatenate([_pad_stack(x, r) for x in rows])
+    pr_rows = _pr_rows(p_total)
+    pod = np.zeros((3, pr_rows * LANES), np.int32)
+    for i, v in enumerate((prio, ok, hard)):
+        pod[i, :p_total] = np.asarray(v).astype(np.int64)
+    return PreemptPlan(
+        k=k, table0=table0, pod=pod.reshape(3, pr_rows, LANES),
+        meta=np.array([seq_next], np.int32),
+    )
 
 
 # ordered (TermsPlan field, memory space) spec of the term-block kernel
@@ -1690,7 +1776,8 @@ def _stream_pack(terms: TermsPlan, u_n: int,
 
 def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
                  has_taint: bool, has_pins: bool, s_n: int, g_n: int,
-                 pw: int, sc: Optional[StoreCfg], tc: Optional[TermsCfg]):
+                 pw: int, sc: Optional[StoreCfg], tc: Optional[TermsCfg],
+                 pk: int = 0):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -1704,6 +1791,7 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
         18 + int(has_nodeaff) + int(has_taint)
         + (3 if s_n else 0) + (6 if g_n else 0) + (3 if pw else 0)
         + (len(_STORE_FIELDS) if sc is not None else 0)
+        + (3 if pk else 0)
     )
     stream = tc is not None and tc.stream
     term_fields = _STREAM_TERM_FIELDS if stream else _TERM_FIELDS
@@ -1711,7 +1799,7 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
     # storage plans export the final VG usage (capacity vg_util reads
     # it); streamed plans append the mutated HBM state buffer as an
     # extra output (ANY space; never fetched to the host)
-    N_OUT = 7 + int(sc is not None) + int(stream)
+    N_OUT = 7 + int(sc is not None) + int(stream) + (2 if pk else 0)
 
     def two_sum(a, b):
         # Knuth 2Sum (branch-free, round-to-nearest f32): s + err == a + b
@@ -1761,6 +1849,10 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
             conflw_ref = next(it)  # (U*Pw,) SMEM
         if sc is not None:
             srf = {nm: next(it) for nm, _ in _STORE_FIELDS}
+        if pk:
+            pre_tab0_ref = next(it)  # (F*K, R, C) ANY, DMAed to scratch
+            pre_pod_ref = next(it)  # (3, Pr, C): priority, allowed, hard
+            pre_meta_ref = next(it)  # (1,) SMEM next commit sequence
         if tc is not None:
             tr = dict(zip((nm for nm, _ in term_fields),
                           refs[BASE_IN : BASE_IN + TERM_IN]))
@@ -1788,6 +1880,8 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
             vg_out_ref = outs[oi]
             oi += 1
         state_out_ref = outs[oi] if stream else None
+        if pk:
+            pre_node_ref, pre_vic_ref = outs[-2:]
         extra = refs[BASE_IN + TERM_IN + N_OUT :]
         ei = 0
         if s_n:
@@ -1817,7 +1911,10 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
                 (tgt_s, pref_s, panti_s, antib_s, tposb_s, group_s,
                  gtot_s, soft_s) = extra[ei : ei + 8]
                 ei += 8
-        if s_n or g_n or pw or sc is not None or tc is not None:
+        if pk:
+            tab_s, flag_s = extra[ei : ei + 2]
+            ei += 2
+        if s_n or g_n or pw or sc is not None or tc is not None or pk:
             dma_sem = extra[ei]
 
         shape = valid_ref.shape
@@ -1841,7 +1938,10 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
         st_nzc_ref[:] = inzc_ref[:]
         st_nzm_ref[:] = inzm_ref[:]
         st_p_ref[:] = ipc_ref[:]
-        if s_n or g_n or pw or sc is not None or tc is not None:
+        if pk:
+            flag_s[0] = jnp.int32(0)
+            flag_s[1] = pre_meta_ref[0]
+        if s_n or g_n or pw or sc is not None or tc is not None or pk:
             # init states arrive in ANY (HBM) so they do not double the
             # VMEM footprint of their scratch copies; one DMA each
             from jax.experimental.pallas import tpu as pltpu_mod
@@ -1853,6 +1953,8 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
                 copies.append((igpu0_ref, ugpu_s))
             if pw:
                 copies.append((ports0_ref, ports_pl))
+            if pk:
+                copies.append((pre_tab0_ref, tab_s))
             if sc is not None:
                 copies += [
                     (srf["ivg0"], vgu_s),
@@ -1885,6 +1987,147 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
                 cp = pltpu_mod.make_async_copy(src_ref, dst_ref, dma_sem)
                 cp.start()
                 cp.wait()
+
+        def dry_run(pr, lane, prio, rc, rm, re, has_req, feas_u,
+                    used_c, used_m, used_e, used_nzc, used_nzm, pod_cnt):
+            """DefaultPreemption's dry run, pick and eviction for the pod
+            at row `pr` (ops/preempt.dry_run over int32 tiles; slot sets
+            as K-bit masks): writes the evicted state, the compacted
+            slots and the pod's placement, preemption node and victim
+            bits."""
+            F = {f: i for i, f in enumerate(_PRE_FIELDS)}
+
+            def slot(f, k):
+                return tab_s[F[f] * pk + k]
+
+            def bit(mask, k):
+                return ((mask >> k) & 1) != 0
+
+            def fits(c, m, e, n):
+                fit = (c + rc <= alloc_c) & (m + rm <= alloc_m) & (e + re <= alloc_e)
+                return (n + 1 <= alloc_p) & (fit | (has_req == 0))
+
+            lower = jnp.zeros(shape, jnp.int32)
+            hard = jnp.zeros(shape, jnp.int32)
+            for k in range(pk):
+                low_k = (slot("valid", k) != 0) & (slot("prio", k) < prio)
+                lower = lower | jnp.where(low_k, 1 << k, 0)
+                hard = hard | jnp.where(low_k & (slot("hard", k) != 0), 1 << k, 0)
+
+            def colsum(f, mask):
+                return sum(jnp.where(bit(mask, k), slot(f, k), 0) for k in range(pk))
+
+            n_lower = sum(jnp.where(bit(lower, k), 1, 0) for k in range(pk))
+            c = used_c - colsum("mcpu", lower)
+            m = used_m - colsum("mem", lower)
+            e = used_e - colsum("eph", lower)
+            n = pod_cnt - n_lower
+            # nodesWherePreemptionMightHelp + the fit with every lower pod gone
+            cand = feas_u & valid & (n_lower > 0) & fits(c, m, e, n)
+            escape = (flag_s[0] != 0) | jnp.any(cand & (hard != 0))
+
+            def reprieve(_, carry):
+                # the most important remaining pod (priority descending,
+                # earlier commit first) goes back if the pod still fits
+                rem, vic, c, m, e, n = carry
+                bp = jnp.full(shape, NEG, jnp.int32)
+                bs = jnp.full(shape, BIG, jnp.int32)
+                bk = jnp.full(shape, pk, jnp.int32)
+                for k in range(pk):
+                    p_k, s_k = slot("prio", k), slot("seq", k)
+                    better = bit(rem, k) & ((p_k > bp) | ((p_k == bp) & (s_k < bs)))
+                    bp = jnp.where(better, p_k, bp)
+                    bs = jnp.where(better, s_k, bs)
+                    bk = jnp.where(better, k, bk)
+                pick = sum(jnp.where(bk == k, 1 << k, 0) for k in range(pk))
+                pc, pm, pe = colsum("mcpu", pick), colsum("mem", pick), colsum("eph", pick)
+                keep = (bk < pk) & fits(c + pc, m + pm, e + pe, n + 1)
+                return (
+                    rem & ~pick,
+                    vic | jnp.where(keep, 0, pick),
+                    c + jnp.where(keep, pc, 0),
+                    m + jnp.where(keep, pm, 0),
+                    e + jnp.where(keep, pe, 0),
+                    n + jnp.where(keep, 1, 0),
+                )
+
+            rounds = jnp.max(jnp.where(cand, n_lower, 0))
+            rem0 = jnp.where(cand, lower, 0)
+            _, vic, *_ = jax.lax.fori_loop(
+                0, rounds, reprieve, (rem0, jnp.zeros(shape, jnp.int32), c, m, e, n)
+            )
+
+            # pickOneNodeForPreemption: lowest top victim priority, lowest
+            # priority sum, fewest victims, latest earliest start among the
+            # top-priority victims, first node
+            nv = sum(jnp.where(bit(vic, k), 1, 0) for k in range(pk))
+            pool = cand & (nv > 0)
+            top = jnp.full(shape, NEG, jnp.int32)
+            for k in range(pk):
+                top = jnp.where(bit(vic, k), jnp.maximum(top, slot("prio", k)), top)
+            psum = colsum("prio", vic)
+            early = jnp.full(shape, BIG, jnp.int32)
+            for k in range(pk):
+                early = jnp.where(
+                    bit(vic, k) & (slot("prio", k) == top),
+                    jnp.minimum(early, slot("seq", k)), early,
+                )
+            for x in (top, psum, nv):
+                pool = pool & (x == jnp.min(jnp.where(pool, x, BIG)))
+            pool = pool & (early == jnp.max(jnp.where(pool, early, NEG)))
+            found = jnp.any(pool) & ~escape
+            chosen = jnp.min(jnp.where(pool, idx_mat, BIG))
+
+            # the eviction: release the victims, then compact the chosen
+            # node's slots so valid ones stay a prefix (= ns.pods order)
+            selc = (idx_mat == chosen) & found
+            gone = jnp.where(selc, vic, 0)
+            st_c_ref[:] = used_c - colsum("mcpu", gone)
+            st_m_ref[:] = used_m - colsum("mem", gone)
+            st_e_ref[:] = used_e - colsum("eph", gone)
+            st_nzc_ref[:] = used_nzc - colsum("nz_mcpu", gone)
+            st_nzm_ref[:] = used_nzm - colsum("nz_mem", gone)
+            st_p_ref[:] = pod_cnt - sum(jnp.where(bit(gone, k), 1, 0) for k in range(pk))
+            kept = jnp.sum(jnp.where(
+                selc,
+                sum(jnp.where((slot("valid", k) != 0) & ~bit(vic, k), 1 << k, 0)
+                    for k in range(pk)),
+                0,
+            ))
+
+            def compact(j, src):
+                # slot j takes the next kept slot at or after `src`
+                nxt = jnp.int32(pk)
+                for k in reversed(range(pk)):
+                    nxt = jnp.where((k >= src) & (((kept >> k) & 1) != 0), k, nxt)
+                for f in range(len(_PRE_FIELDS)):
+                    v = jnp.where(nxt < pk, tab_s[f * pk + jnp.minimum(nxt, pk - 1)], 0)
+                    tab_s[f * pk + j] = jnp.where(selc, v, tab_s[f * pk + j])
+                return nxt + 1
+
+            jax.lax.fori_loop(0, pk, compact, jnp.int32(0))
+
+            pre = jnp.where(escape, -2, jnp.where(found, chosen, -1))
+            for ref, v in (
+                (place_ref, jnp.where(found, chosen, -1)),
+                (pre_node_ref, pre),
+                (pre_vic_ref, jnp.sum(gone)),
+            ):
+                row = ref[pl.ds(pr, 1), :]
+                ref[pl.ds(pr, 1), :] = jnp.where(lane, v, row)
+
+        def record_commit(sel, pod_cnt, vals):
+            """Append a committed pod to its node's slots at slot
+            pod_cnt (ops/preempt.record_commit); past the last slot the
+            overflow flag turns every later dry run into an escape."""
+            flag_s[0] = jnp.maximum(
+                flag_s[0], jnp.any(sel & (pod_cnt >= pk)).astype(jnp.int32)
+            )
+            for k in range(pk):
+                put = sel & (pod_cnt == k)
+                for f, v in vals.items():
+                    i = _PRE_FIELDS.index(f) * pk + k
+                    tab_s[i] = jnp.where(put, v, tab_s[i])
 
         def step(p, prev_u):
             # carry = previous pod's class (streamed-terms gather skip;
@@ -2381,6 +2624,30 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
             # only the pod's 128-lane row, lane-selected via the mask
             prow = place_ref[pl.ds(pr, 1), :]
             place_ref[pl.ds(pr, 1), :] = jnp.where(lane, place, prow)
+            if pk:
+                # PostFilter: a failed pod allowed to preempt runs the
+                # dry run; it rewrites the state, the slots and its rows
+                def pre_scalar(s):
+                    return jnp.sum(jnp.where(lane, pre_pod_ref[s, pl.ds(pr, 1), :], 0))
+
+                for ref, v in ((pre_node_ref, -1), (pre_vic_ref, 0)):
+                    row = ref[pl.ds(pr, 1), :]
+                    ref[pl.ds(pr, 1), :] = jnp.where(lane, v, row)
+                pre_prio = pre_scalar(0)
+
+                @pl.when((place == -1) & (pre_scalar(1) != 0))
+                def _():
+                    dry_run(pr, lane, pre_prio, rc, rm, re, has_req,
+                            feas_ref[fu] != 0, used_c, used_m, used_e,
+                            st_nzc, st_nzm, pod_cnt)
+
+                place = jnp.sum(jnp.where(lane, place_ref[pl.ds(pr, 1), :], 0))
+                used_c = st_c_ref[:]
+                used_m = st_m_ref[:]
+                used_e = st_e_ref[:]
+                st_nzc = st_nzc_ref[:]
+                st_nzm = st_nzm_ref[:]
+                pod_cnt = st_p_ref[:]
 
             do = place >= 0
             sel = (idx_mat == place) & do
@@ -2390,6 +2657,14 @@ def _make_kernel(p_total: int, u_n: int, w: tuple, has_nodeaff: bool,
             st_nzc_ref[:] = st_nzc + jnp.where(sel, nzc, 0)
             st_nzm_ref[:] = st_nzm + jnp.where(sel, nzm, 0)
             st_p_ref[:] = pod_cnt + jnp.where(sel, 1, 0)
+            if pk:
+                seq = flag_s[1]
+                record_commit(sel, pod_cnt, {
+                    "valid": 1, "hard": pre_scalar(2), "prio": pre_prio,
+                    "seq": seq, "mcpu": rc, "mem": rm, "eph": re,
+                    "nz_mcpu": nzc, "nz_mem": nzm,
+                })
+                flag_s[1] = seq + do.astype(jnp.int32)
             if s_n or pw:
                 sel_i = sel.astype(jnp.int32)
             if s_n:
@@ -2602,6 +2877,8 @@ def _plan_args_np(plan: PallasPlan) -> list:
         args += [plan.ports0, plan.want_w, plan.confl_w]
     if plan.store is not None:
         args += [getattr(plan.store, name) for name, _ in _STORE_FIELDS]
+    if plan.pre is not None:
+        args += [plan.pre.table0, plan.pre.pod, plan.pre.meta]
     if plan.terms is not None:
         fields = (
             _STREAM_TERM_FIELDS
@@ -2750,21 +3027,23 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
     pr_rows = _pr_rows(p_total)
     tc = plan.terms.cfg if plan.terms is not None else None
     sc = plan.store.cfg if plan.store is not None else None
+    pk = plan.pre.k if plan.pre is not None else 0
     key = (p_total, plan.r, plan.u, plan.w, plan.has_nodeaff, plan.has_taint,
-           plan.has_pins, plan.s_n, plan.g_n, plan.pw, sc, tc, metas,
+           plan.has_pins, plan.s_n, plan.g_n, plan.pw, sc, tc, pk, metas,
            grouped, interpret)
     cached = _COMPILED_CACHE.get(key)
     if cached is not None:
         return cached.fn
     kernel = _make_kernel(p_total, plan.u, plan.w, plan.has_nodeaff,
                           plan.has_taint, plan.has_pins, plan.s_n,
-                          plan.g_n, plan.pw, sc, tc)
+                          plan.g_n, plan.pw, sc, tc, pk)
     rc = (plan.r, LANES)
     base_n = (
         18 + int(plan.has_nodeaff) + int(plan.has_taint)
         + (3 if plan.s_n else 0) + (6 if plan.g_n else 0)
         + (3 if plan.pw else 0)
         + (len(_STORE_FIELDS) if sc is not None else 0)
+        + (3 if pk else 0)
     )
     stream = tc is not None and tc.stream
     term_fields = _STREAM_TERM_FIELDS if stream else _TERM_FIELDS
@@ -2794,6 +3073,10 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
             elif space == "smem":
                 smem_idx.add(off + soff)
         off += len(_STORE_FIELDS)
+    if pk:
+        any_idx.add(off)  # table0
+        smem_idx.add(off + 2)  # next commit sequence
+        off += 3
     if tc is not None:
         for toff, (_, space) in enumerate(term_fields):
             if space == "any":
@@ -2802,7 +3085,7 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
                 smem_idx.add(base_n + toff)
 
     scratch = []
-    if plan.s_n or plan.g_n or plan.pw or sc is not None or tc is not None:
+    if plan.s_n or plan.g_n or plan.pw or sc is not None or tc is not None or pk:
         from jax.experimental.pallas import tpu as _pltpu
 
         rl = (plan.r, LANES)
@@ -2837,6 +3120,11 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
                     _pltpu.VMEM((tc.a, SUBLANES, LANES), jnp.int32),  # gtot
                     _pltpu.VMEM((tc.csn,) + rl, jnp.int32),  # soft non-host
                 ]
+        if pk:
+            scratch += [
+                _pltpu.VMEM((len(_PRE_FIELDS) * pk,) + rl, jnp.int32),  # slots
+                _pltpu.SMEM((2,), jnp.int32),  # overflow flag, next sequence
+            ]
         scratch.append(_pltpu.SemaphoreType.DMA)
 
     n_ps = 8 * pr_rows * LANES
@@ -2887,6 +3175,11 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
                 jax.ShapeDtypeStruct((tc.srows, plan.r, LANES), jnp.int32)
             )
             out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        if pk:
+            # per pod: the preemption node (or NONE / ESCAPE) and the
+            # victims' slots as a bit mask
+            out_shape += [jax.ShapeDtypeStruct((pr_rows, LANES), jnp.int32)] * 2
+            out_specs += [pl.BlockSpec(memory_space=pltpu.VMEM)] * 2
         outs = pl.pallas_call(
             kernel,
             out_shape=tuple(out_shape),
@@ -2903,6 +3196,8 @@ def kernel_call(plan: PallasPlan, p_total: int, metas: tuple,
         fetched = list(outs[:7])
         if sc is not None:
             fetched.append(outs[7].reshape(sc.v * plan.r, LANES))
+        if pk:
+            fetched += list(outs[-2:])
         return jnp.concatenate(fetched, axis=0)
 
     _COMPILED_CACHE[key] = _Compiled(fn=pod_scan_fused)
@@ -3021,12 +3316,23 @@ def decode_scan_output(plan: PallasPlan, out: np.ndarray, p_total: int):
     # map padded slots: any placement index beyond n means "no node"
     place = np.where((place >= 0) & (place >= plan.n), -1, place)
     st = states.reshape(6, -1)[:, : plan.n].astype(np.int64)
+    if plan.pre is not None:
+        # the last two row blocks: preemption node and victim bit mask
+        tail = out[out.shape[0] - 2 * pr_rows:].reshape(2, -1)[:, :p_total]
+        bits = tail[1].astype(np.int64)
+        pre_final = {
+            "pre_node": tail[0].astype(np.int64),
+            "victims": ((bits[:, None] >> np.arange(plan.pre.k)) & 1).astype(bool),
+        }
+    else:
+        pre_final = {}
     final = {
         "used_mcpu": st[0],
         "used_mem": st[1] * plan.s_mem,
         "nz_mcpu": st[3],
         "nz_mem": st[4] * plan.s_nzmem,
         "pod_cnt": st[5],
+        **pre_final,
     }
     if plan.store is not None:
         v = plan.store.cfg.v

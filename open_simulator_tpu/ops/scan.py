@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import profile as _obs_profile
+from . import preempt as _preempt
 from ..scheduler.schedconfig import DEFAULT_SCORE_WEIGHTS as _DEFAULT_WEIGHTS
 
 MAX_SCORE = 100
@@ -72,6 +73,9 @@ class ScanFeatures(NamedTuple):
     # Go math/rand stream carried in the scan state (_sample_select);
     # requires init.rng_hist (the GoRand 607-output history)
     sample: bool = False
+    # DefaultPreemption's dry run inside the step (ops/preempt.py);
+    # requires init.preempt and the scan's preempt_in inputs
+    preempt: bool = False
 
     @property
     def terms(self) -> bool:
@@ -249,6 +253,9 @@ class ScanState(NamedTuple):
     # None (the default) on non-sample batches keeps the pytree stable.
     rng_hist: jnp.ndarray = None  # [607] uint64
     rng_overflow: jnp.ndarray = None  # [] bool
+    # the per-node table of committed pods the device dry run reads
+    # (ops/preempt.PreemptState); None unless features.preempt
+    preempt: object = None
 
 
 class _LocalCtx:
@@ -827,6 +834,7 @@ def run_scan_masked(
     pod_active,
     features=None,
     weights=None,
+    preempt_in=None,
 ):
     """run_scan with scenario masks for the capacity sweep
     (pkg/apply/apply.go:186-239 re-imagined as a batched what-if):
@@ -853,6 +861,12 @@ def run_scan_masked(
     With features.sample the returned placements are a (placements,
     consumed_words) PAIR (see run_scan) and init.rng_hist must carry
     the GoRand 607-output history.
+
+    With features.preempt (ops/preempt.py) init.preempt carries the
+    committed-pod table, `preempt_in` is a preempt.PreemptInput of
+    per-pod arrays, and the returned placements are a (placements,
+    pre_node[P], victims[P, K]) triple: where pre_node[p] is a node,
+    pod p evicted the victims' slots of that node and then placed.
     """
     if features is not None and weights is not None:
         raise ValueError(
@@ -869,8 +883,18 @@ def run_scan_masked(
             )
         if init.rng_overflow is None:
             init = init._replace(rng_overflow=jnp.zeros((), bool))
+    if features.preempt and (init.preempt is None or preempt_in is None):
+        raise ValueError("features.preempt needs init.preempt and preempt_in")
+    if features.preempt and (
+        features.gpu or features.storage or features.terms or features.ports
+        or features.scalars or features.sample
+    ):
+        # the device dry run reads resources and pod counts only
+        # (ops/preempt.py scope); the host never asks for more
+        raise ValueError("features.preempt outside the device dry run's scope")
     return _run_scan_compiled(
-        features, static, init, class_of_pod, pinned_node, node_valid, pod_active
+        features, static, init, class_of_pod, pinned_node, node_valid,
+        pod_active, preempt_in if features.preempt else None,
     )
 
 
@@ -882,6 +906,7 @@ def _run_scan_compiled_impl(
     pinned_node,
     node_valid,
     pod_active,
+    preempt_in=None,
     ctx=LOCAL_CTX,
 ):
     # `ctx` (static at trace time) abstracts the node axis: LOCAL_CTX
@@ -893,7 +918,10 @@ def _run_scan_compiled_impl(
     n = static.alloc_mcpu.shape[0]
 
     def step(state: ScanState, inp):
-        u, pin, active = inp
+        if features.preempt:
+            u, pin, active, pre_prio, pre_ok, pre_hard = inp
+        else:
+            u, pin, active = inp
         feasible = static.static_feasible[u] & node_valid
         # NodeResourcesFit (noderesources/fit.go:230-303)
         fit_pods = state.pod_cnt + 1 <= static.alloc_pods
@@ -1076,6 +1104,23 @@ def _run_scan_compiled_impl(
             pin_ok = ctx.gather_vec(node_valid, jnp.maximum(pin, 0))
             placement = jnp.where((pin >= 0) & ~pin_ok, INACTIVE, placement)
         placement = jnp.where(active, placement, INACTIVE)
+        if features.preempt:
+            # PostFilter: an armed pod that failed every node runs the
+            # dry run; the retry cycle on the evicted state can only
+            # choose the preemption node (ops/preempt.py)
+            k = state.preempt.valid.shape[0]
+
+            def preempt_cycle(st):
+                st, pre_node, victims = _preempt.dry_run(static, st, u, pre_prio, node_valid)
+                return st, jnp.maximum(pre_node, -1).astype(placement.dtype), pre_node, victims
+
+            def no_preemption(st):
+                return (st, placement, jnp.asarray(_preempt.NONE, jnp.int64),
+                        jnp.zeros((k,), bool))
+
+            state, placement, pre_node, pre_victims = jax.lax.cond(
+                (placement == -1) & pre_ok, preempt_cycle, no_preemption, state
+            )
 
         # ---- commit ----
         commit = placement >= 0
@@ -1132,6 +1177,13 @@ def _run_scan_compiled_impl(
             soft_counts=soft_counts,
             rng_hist=new_rng_hist,
             rng_overflow=new_rng_overflow,
+            preempt=(
+                _preempt.record_commit(
+                    static, state, u, placement, commit, pre_prio, pre_hard
+                )
+                if features.preempt
+                else state.preempt
+            ),
         )
         if features.sample:
             # per-pod word consumption rides along so the priority-scan
@@ -1139,11 +1191,14 @@ def _run_scan_compiled_impl(
             # consumed draws for the whole batch, but escaped tails are
             # discarded and rescheduled)
             return new_state, (placement, consumed)
+        if features.preempt:
+            return new_state, (placement, pre_node, pre_victims)
         return new_state, placement
 
-    final_state, placements = jax.lax.scan(
-        step, init, (class_of_pod, pinned_node, pod_active)
-    )
+    xs = (class_of_pod, pinned_node, pod_active)
+    if features.preempt:
+        xs = xs + tuple(preempt_in)
+    final_state, placements = jax.lax.scan(step, init, xs)
     # sample mode: placements is a (placements[P], consumed_words[P])
     # pair — the engine unpacks it (no other caller runs sample)
     return placements, final_state

@@ -135,6 +135,8 @@ class Simulator:
         self.cluster_pods: List[dict] = []
         self._engine = None  # TpuEngine, created once per cluster
         self._batch_map = None  # (batch indices, orig->pos) of the last batch
+        # per-batch device dry-run inputs (_device_preempt_info), or None
+        self._pre_info = None
         self._events: List[PreemptionEvent] = []  # preemptions this batch
         # optional serial-loop observer (shadow/record.py): an object
         # with `prebound(pod_snapshot)` and `decision(pod_snapshot,
@@ -403,21 +405,28 @@ class Simulator:
         the gate — conservative, never wrong: the escape replays that
         pod through the full serial cycle either way.
 
-        Victims evicted by an escape rejoin the serial queue at the
-        BACK (behind the remaining batch), so they are deferred into a
-        final serial segment in eviction order — the same queue
-        equivalence argument as the round-3 hybrid (vendor
-        scheduling_queue semantics under the one-pod-in-flight
-        handshake)."""
+        A failing armed pod does not always escape: where the batch is
+        in the device dry run's scope (_device_preempt_info), the scan
+        itself runs DefaultPreemption for it (ops/preempt.py) and the
+        replay applies the evictions at its step. Only a step the
+        device reports out of scope escapes.
+
+        Victims evicted by an escape or on the device rejoin the queue
+        at the BACK (behind the remaining batch), so they are deferred
+        into a final segment in eviction order, scheduled through this
+        same engine — the same queue equivalence argument as the
+        round-3 hybrid (vendor scheduling_queue semantics under the
+        one-pod-in-flight handshake)."""
         import numpy as np
 
         from .engine import SampleRngOverflow
         from .preemption import tier_escape_mask
         from ..obs.explain import EXPLAIN
-        from ..utils.trace import GLOBAL
+        from ..utils.trace import COUNTERS, GLOBAL, phase
 
         failed: List[UnscheduledPod] = []
         deferred: List[dict] = []
+        evicted: set = set()  # ids of device-evicted pods (cluster_pods)
         p = len(pods)
         prios = np.asarray(prios, dtype=np.int64)
         rounds = escapes = 0
@@ -450,7 +459,7 @@ class Simulator:
                 f, escape_at = self._scan_and_commit(
                     pods, armed=armed, policy_gate=policy_gate,
                     prios=prios, start=start, reuse_batch=rounds > 1,
-                    groups=groups,
+                    groups=groups, deferred=deferred, evicted=evicted,
                 )
             except SampleRngOverflow:
                 # nothing from this round committed (the engine raises
@@ -485,13 +494,29 @@ class Simulator:
             f4, d4 = self._schedule_pods_oracle(pods[start:], defer_victims=True)
             failed.extend(f4)
             deferred.extend(d4)
+        if evicted:
+            self.cluster_pods = [q for q in self.cluster_pods if id(q) not in evicted]
+        COUNTERS.inc("preempt_serial_escapes_total", escapes)
         if deferred:
-            f3, _ = self._schedule_pods_oracle(deferred)
-            failed.extend(f3)
+            with phase("engine/deferred"):
+                failed.extend(self._schedule_deferred(deferred))
         GLOBAL.note("engine", "priority-scan")
         GLOBAL.note("priority-scan-rounds", rounds)
         GLOBAL.note("priority-scan-escapes", escapes)
         GLOBAL.note("priority-scan-tiers", tiers_round1)
+        return failed
+
+    def _schedule_deferred(self, pods: List[dict]) -> List[UnscheduledPod]:
+        """The victims of a priority batch, in eviction order, as a
+        batch of their own through the same engine (their own victims
+        are deferred behind them in turn); short segments go serial,
+        where victims rejoin the queue at its back — the same order."""
+        from .preemption import batch_priorities
+
+        if len(pods) >= MIN_SCAN_RUN:
+            prios = batch_priorities(pods, self.oracle._prio_resolver)
+            return self._schedule_pods_priority(pods, prios)
+        failed, _ = self._schedule_pods_oracle(pods)
         return failed
 
     def _schedule_pods_oracle(
@@ -502,6 +527,8 @@ class Simulator:
         hybrid path re-enqueues them after its scan segment."""
         import copy
         from collections import deque
+
+        from ..utils.trace import COUNTERS
 
         failed: List[UnscheduledPod] = []
         deferred: List[dict] = []
@@ -537,6 +564,7 @@ class Simulator:
             # chains strictly descend.
             evictions = []
             for ev in self.oracle.drain_preempted():
+                COUNTERS.inc("preempt_victims_total")
                 self._events.append(
                     PreemptionEvent(
                         victim=ev.pod, node_name=ev.node_name, preemptor=ev.preemptor
@@ -567,6 +595,8 @@ class Simulator:
         start: int = 0,
         reuse_batch: bool = False,
         groups=None,
+        deferred=None,
+        evicted=None,
     ):
         """Dispatch one scan round over `pods[start:]` and replay the
         placements onto the oracle in order. Returns
@@ -589,10 +619,16 @@ class Simulator:
         call in the same batch loop (engine.begin_batch ran once; each
         round is a masked scan over the full-batch shapes, so escape
         rounds never re-encode or recompile).
+
+        Where the batch is in the device dry run's scope
+        (_device_preempt_info), armed pods that fail preempt inside the
+        scan, and only a pod the device reports out of scope escapes;
+        its victims go to `deferred` and their ids to `evicted`.
         """
         import numpy as np
 
         from .engine import TpuEngine
+        from ..ops.preempt import ESCAPE
         from ..utils.trace import phase
 
         p = len(pods)
@@ -643,21 +679,42 @@ class Simulator:
             if len(bidx):
                 eng.begin_batch(batch_pods, groups=batch_groups)
             self._batch_map = (bidx, pos_of)
+            self._pre_info = (
+                self._device_preempt_info(batch_pods, batch_groups, prios[bidx])
+                if armed is not None and policy_gate and len(bidx)
+                else None
+            )
         bidx, pos_of = self._batch_map
         b = len(bidx)
+        pre = None
         if b:
             pos_start = int(np.searchsorted(bidx, start))
             active = np.zeros(b, dtype=bool)
             active[pos_start:] = True
-            placements = eng.scan_active(active)
+            request = None
+            if self._pre_info is not None:
+                request = self._preempt_request(armed, bidx, pos_start, start)
+            if request is None:
+                placements = eng.scan_active(active)
+            else:
+                placements = eng.scan_active(active, preempt=request)
+            pre = eng.last_preempt
         else:
             pos_start = 0
             placements = np.zeros(0, dtype=np.int64)
-        # escape detection: one vectorized pass over the active suffix,
-        # then the per-candidate preemptionPolicy gate on FAILING pods
-        # only (mirrors run_preemption's PodEligibleToPreemptOthers)
         escape_at = None
-        if armed is not None and b and pos_start < b:
+        if self._pre_info is not None:
+            # the device ran DefaultPreemption for every armed failure;
+            # only a step it reports out of scope escapes
+            if pre is not None:
+                esc = np.flatnonzero(pre[0][pos_start:] == ESCAPE)
+                if esc.size:
+                    escape_at = int(bidx[pos_start + int(esc[0])])
+        elif armed is not None and b and pos_start < b:
+            # escape detection: one vectorized pass over the active
+            # suffix, then the per-candidate preemptionPolicy gate on
+            # FAILING pods only (mirrors run_preemption's
+            # PodEligibleToPreemptOthers)
             seg = placements[pos_start:]
             seg_pinned = np.asarray(eng._batch.pinned_node)[pos_start:] >= 0
             cand = (seg < 0) & ~seg_pinned
@@ -681,10 +738,71 @@ class Simulator:
         failed: List[UnscheduledPod] = []
         stop = p if escape_at is None else escape_at
         with phase("engine/replay"):
-            self._replay_window(pods, placements, start, stop, prios, failed)
+            self._replay_window(
+                pods, placements, start, stop, prios, failed,
+                pre=pre, deferred=deferred, evicted=evicted,
+            )
         return failed, escape_at
 
-    def _replay_window(self, pods, placements, start, stop, prios, failed):
+    def _device_preempt_info(self, batch_pods, batch_groups, prio_b):
+        """Per-batch inputs of the device dry run, or None where the
+        batch is out of its scope (ops/preempt.py): preemption off,
+        sample-mode selectHost, or a feature through which victims
+        could affect a preemptor beyond NodeResourcesFit and the pod
+        count (host ports, scalar resources, GPU share, open-local
+        volumes, inter-pod affinity or spread terms), or a priority
+        outside int32. Returns (prio_b, never_b, hard_b, n_pinned)."""
+        import numpy as np
+
+        from .preemption import pdb_matched
+
+        oracle = self.oracle
+        f = self._engine._features
+        if (
+            not oracle.enable_preemption
+            or oracle.select_host == "sample"
+            or f.gpu or f.storage or f.terms or f.ports or f.scalars
+        ):
+            return None
+        if prio_b.size and (prio_b.min() < -(1 << 31) or prio_b.max() >= 1 << 31):
+            return None
+        reps = batch_pods if batch_groups is None else batch_groups[1]
+        never = np.fromiter(
+            (oracle.pod_preemption_policy(q) == "Never" for q in reps),
+            dtype=bool, count=len(reps),
+        )
+        hard = np.fromiter(
+            (pdb_matched(q, oracle.pdbs) for q in reps), dtype=bool, count=len(reps)
+        )
+        if batch_groups is not None:
+            never, hard = never[batch_groups[0]], hard[batch_groups[0]]
+        n_pinned = int((np.asarray(self._engine._batch.pinned_node) >= 0).sum())
+        return prio_b, never, hard, n_pinned
+
+    def _preempt_request(self, armed, bidx, pos_start, start):
+        """This round's engine.PreemptRequest: armed pods of the active
+        suffix whose preemptionPolicy is not Never; None where none is."""
+        import numpy as np
+
+        from .engine import PreemptRequest
+        from .preemption import victim_out_of_scope
+
+        prio_b, never_b, hard_b, n_pinned = self._pre_info
+        ok = np.zeros(len(bidx), dtype=bool)
+        ok[pos_start:] = (
+            np.asarray(armed, dtype=bool)[bidx[pos_start:] - start] & ~never_b[pos_start:]
+        )
+        if not ok.any():
+            return None
+        oracle = self.oracle
+        return PreemptRequest(
+            prio_b, ok, hard_b,
+            lambda ns, pod: victim_out_of_scope(oracle, ns, pod),
+            extra_slots=n_pinned,
+        )
+
+    def _replay_window(self, pods, placements, start, stop, prios, failed,
+                       pre=None, deferred=None, evicted=None):
         """Replay committed placements for `pods[start:stop]` in order.
 
         Contiguous runs of side-effect-free placements commit in bulk
@@ -693,12 +811,21 @@ class Simulator:
         pinned, failed, or a class with GPU/storage/extender side
         effects — which takes the exact per-pod path at its position,
         so oracle state evolves in the same order as the serial cycle
-        (failure reasons read the state of their own step)."""
+        (failure reasons read the state of their own step).
+
+        A pod that preempted on the device (`pre`: engine.last_preempt)
+        is an event pod too: its victims are evicted at its step, in
+        MoreImportantPod order, recorded as PreemptionEvents and
+        appended to `deferred`, then the pod commits. Failure reasons
+        are computed once per class over a run of failures with no
+        commit or eviction between them: the state, and so the
+        message's counts, are the same for each."""
         import numpy as np
 
         if stop <= start:
             return
         from ..obs.explain import EXPLAIN
+        from ..utils.trace import phase
 
         eng = self._engine
         bidx, pos_of = self._batch_map
@@ -737,8 +864,19 @@ class Simulator:
             w_cls = np.zeros(stop - start, dtype=np.int64)
             w_pin = np.zeros(stop - start, dtype=bool)
             bulk_mask = np.zeros(stop - start, dtype=bool)
+        if pre is not None:
+            pre_node, pre_victims = pre
+            w_pre = np.where(w_pos >= 0, pre_node[np.clip(w_pos, 0, None)], -1)
+            bulk_mask &= w_pre < 0
+        else:
+            w_pre = np.full(stop - start, -1, dtype=np.int64)
+        # state version: bumped by every commit and eviction, so a
+        # class's failure reasons are reused only while nothing changed
+        version = 0
+        reasons_of = {}
 
         def bulk(a, b):
+            nonlocal version
             if b <= a:
                 return
             sl = pods[start + a: start + b]
@@ -747,6 +885,7 @@ class Simulator:
                 prios=None if prios is None else prios[start + a: start + b],
             )
             cluster_pods.extend(sl)
+            version += 1
 
         prev = 0
         for e in np.flatnonzero(~bulk_mask).tolist():
@@ -756,19 +895,34 @@ class Simulator:
             if w_pos[e] < 0:
                 # dangling: tracked in the cluster, never scheduled
                 cluster_pods.append(pod)
-            elif w_pin[e]:
+                continue
+            if w_pin[e]:
                 oracle.place_existing_pod(pod)
                 cluster_pods.append(pod)
-            elif w_place[e] < 0:
+                version += 1
+                continue
+            if w_place[e] < 0:
                 # oracle state here equals the scan state at this step
-                # (commits are replayed in order), so reasons are exact
-                _, reasons, _ = oracle._find_feasible(pod)
+                # (commits are replayed in order), so reasons are exact;
+                # a preemptor's reasons are those of its first cycle
+                cls = int(w_cls[e])
+                hit = reasons_of.get(cls)
+                if hit is None or hit[0] != version or EXPLAIN.enabled:
+                    with phase("engine/failure-reasons"):
+                        _, reasons, _ = oracle._find_feasible(pod)
+                    hit = reasons_of[cls] = (version, reasons)
                 failed.append(
                     UnscheduledPod(
-                        pod=pod, reason=Oracle._failure_message(pod, reasons)
+                        pod=pod, reason=Oracle._failure_message(pod, hit[1])
                     )
                 )
-            else:
+            if w_pre[e] >= 0:
+                with phase("engine/preempt-replay"):
+                    self._evict_device_victims(
+                        pod, int(w_pre[e]), pre_victims[w_pos[e]], deferred, evicted
+                    )
+                version += 1
+            if w_place[e] >= 0:
                 if (
                     EXPLAIN.enabled
                     and EXPLAIN.target is not None
@@ -781,7 +935,52 @@ class Simulator:
                 # GPU/storage/extender side effects: exact per-pod bind
                 eng.commit_host_at(pod, int(w_place[e]), int(w_pos[e]))
                 cluster_pods.append(pod)
+                version += 1
         bulk(prev, stop - start)
+
+    def _evict_device_victims(self, pod, node_idx, slots, deferred, evicted):
+        """Apply a preemption the device decided: the victims are the
+        marked slots of the node, which are indices into `ns.pods`
+        (ops/preempt.py keeps that layout). Evicted in MoreImportantPod
+        order, as run_preemption lists them."""
+        import numpy as np
+
+        from ..obs.explain import EXPLAIN
+        from ..runtime.errors import ConformanceError
+        from ..utils.trace import COUNTERS
+
+        oracle = self.oracle
+        ns = oracle.nodes[node_idx]
+        victims = [ns.pods[i] for i in np.flatnonzero(slots).tolist()]
+        prio = oracle.pod_priority(pod)
+        if not victims or any(oracle.pod_priority(v) >= prio for v in victims):
+            raise ConformanceError(
+                f"device preemption on {ns.name} does not match its pods"
+            )
+        victims.sort(key=lambda v: (-oracle.pod_priority(v), oracle.commit_seq_of(v)))
+        preemptor = (pod.get("metadata") or {}).get("name", "")
+        for v in victims:
+            oracle.evict_pod(ns, v)
+            self._events.append(
+                PreemptionEvent(victim=v, node_name=ns.name, preemptor=preemptor)
+            )
+            evicted.add(id(v))
+            deferred.append(v)
+        COUNTERS.inc("preempt_device_total")
+        COUNTERS.inc("preempt_victims_total", len(victims))
+        if EXPLAIN.enabled and EXPLAIN.should_record(pod):
+            EXPLAIN.annotate(
+                pod,
+                preemption_node=ns.name,
+                preempted=[
+                    "%s/%s"
+                    % (
+                        (v.get("metadata") or {}).get("namespace") or "default",
+                        (v.get("metadata") or {}).get("name", ""),
+                    )
+                    for v in victims
+                ],
+            )
 
     def node_status(self) -> List[NodeStatus]:
         out = []

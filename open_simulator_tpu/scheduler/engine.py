@@ -36,7 +36,7 @@ from ..ops.encode import (
 from ..runtime.errors import GuardError
 from .oracle import Oracle
 
-__all__ = ["SampleRngOverflow", "TpuEngine"]
+__all__ = ["PreemptRequest", "SampleRngOverflow", "TpuEngine"]
 
 # per-class summary integers above this magnitude lose int64 headroom
 # in the bulk scatter-add; such classes (a >2^55-byte request is ~36 PB
@@ -50,6 +50,39 @@ class SampleRngOverflow(GuardError, RuntimeError):
     BEFORE any commit is replayed, so the caller (core._schedule_pods)
     can rerun the batch on the serial oracle, whose rejection loop is
     unbounded."""
+
+
+class PreemptRequest:
+    """What a priority round hands scan_active for the device dry run
+    (ops/preempt.py): per batch position the effective priority, whether
+    a failure there runs the dry run (armed, preemptionPolicy not Never),
+    and whether the pod, once committed, is out of scope as a victim;
+    `hard_of(ns, pod)` says the same of pods already on a node.
+    `extra_slots` counts the batch's pinned pods (table_slots)."""
+
+    def __init__(self, prio, ok, hard, hard_of, extra_slots: int = 0):
+        self.prio = np.asarray(prio, np.int64)
+        self.ok = np.asarray(ok, bool)
+        self.hard = np.asarray(hard, bool)
+        self.hard_of = hard_of
+        self.extra_slots = extra_slots
+
+    def table(self, oracle, cluster, batch):
+        """(ops/preempt.table_np slots, the next commit sequence)."""
+        from ..ops import preempt
+
+        k = preempt.table_slots(oracle, cluster, batch, self.extra_slots)
+        return preempt.table_np(oracle, k, self.hard_of), oracle._seq_counter + 1
+
+    def inputs(self):
+        import jax.numpy as jnp
+
+        from ..ops import preempt
+
+        return preempt.PreemptInput(
+            prio=jnp.asarray(self.prio), ok=jnp.asarray(self.ok),
+            hard=jnp.asarray(self.hard),
+        )
 
 
 class TpuEngine:
@@ -85,6 +118,9 @@ class TpuEngine:
         # path and scenario batches across the scenario axis
         self.mesh = None
         self._mesh_retired = False
+        # (pre_node[P], victims[P, K]) of the last scan_active that ran
+        # the device dry run, else None
+        self.last_preempt = None
 
     def cluster_static(self) -> ClusterStatic:
         # keyed on (node count, alloc epoch): GPU-share Reserve mutates
@@ -131,7 +167,8 @@ class TpuEngine:
                 )
 
     def scan_active(
-        self, active: np.ndarray, valid: Optional[np.ndarray] = None
+        self, active: np.ndarray, valid: Optional[np.ndarray] = None,
+        preempt=None,
     ) -> np.ndarray:
         """One masked scan over the begin_batch encoding against the
         oracle's CURRENT state. Returns placements for the full batch:
@@ -143,7 +180,14 @@ class TpuEngine:
         WITHOUT nodes X" as one warm dispatch this way (the scenario
         node mask of the chaos substrate, ops.scan.run_scan_masked
         node_valid). Same shapes, so a masked query re-dispatches the
-        compiled scan without recompiling."""
+        compiled scan without recompiling.
+
+        `preempt` (core.py, the priority path) is a PreemptRequest of
+        per-batch-position arrays: a pod with `ok` set that fails every
+        node runs DefaultPreemption's dry run on the device
+        (ops/preempt.py): in the fused kernel where its scope allows,
+        else on the XLA scan. Its (pre_node[P], victims[P, K]) land in
+        `self.last_preempt`."""
         import jax.numpy as jnp
 
         from ..ops import pallas_scan
@@ -154,6 +198,10 @@ class TpuEngine:
         oracle = self.oracle
         batch = self._batch
         sample = bool(getattr(self._features, "sample", False))
+        features = self._features
+        self.last_preempt = None
+        if preempt is not None:
+            features = features._replace(preempt=True)
         with phase("engine/encode"):
             cluster = self.cluster_static()
             node_valid = (
@@ -163,11 +211,15 @@ class TpuEngine:
             )
             with phase("engine/encode-state"):
                 dyn = encode_dynamic(oracle, cluster)
+                pre_tab = None if preempt is None else preempt.table(oracle, cluster, batch)
             with phase("engine/kernel-plan"):
                 plan = (
                     pallas_scan.build_plan(
-                        cluster, batch, dyn, self._features,
-                        weights=self._features.weights,
+                        cluster, batch, dyn, features,
+                        weights=features.weights,
+                        preempt=None if preempt is None else (
+                            *pre_tab, preempt.prio, preempt.ok, preempt.hard
+                        ),
                     )
                     if pallas_scan.should_use()
                     else None
@@ -180,6 +232,10 @@ class TpuEngine:
                         self._scan_static = to_scan_static(cluster, batch)
                         self._scan_static_cluster = cluster
                     init = to_scan_state(dyn, batch)
+                    if preempt is not None:
+                        from ..ops.preempt import encode_table
+
+                        init = init._replace(preempt=encode_table(*pre_tab))
                 if sample:
                     # the scan consumes the oracle's Go RNG stream: hand
                     # its 607-output history in via the carry, and (after
@@ -197,7 +253,7 @@ class TpuEngine:
         # ride this (parallel/mesh.py). Classified faults degrade to
         # the single-device path below, trace-noted.
         mesh_route = None
-        if plan is None and not sample and not self._mesh_retired:
+        if plan is None and not sample and preempt is None and not self._mesh_retired:
             from ..parallel import mesh as mesh_mod
 
             m = self.mesh if self.mesh is not None else mesh_mod.current_mesh()
@@ -237,9 +293,11 @@ class TpuEngine:
                 )
                 fetched = np.asarray(out_d)  # blocks on device completion
                 profile.record_d2h(fetched.nbytes)
-                out, _final = pallas_scan.decode_scan_output(
+                out, final = pallas_scan.decode_scan_output(
                     plan, fetched, len(batch.class_of_pod)
                 )
+            if preempt is not None:
+                self.last_preempt = (final["pre_node"], final["victims"])
             return np.asarray(out)
         if mesh_route is not None:
             from ..parallel import mesh as mesh_mod
@@ -273,10 +331,14 @@ class TpuEngine:
                 jnp.asarray(batch.pinned_node),
                 jnp.asarray(node_valid),
                 jnp.asarray(np.asarray(active, bool)),
-                features=self._features,
+                features=features,
+                preempt_in=None if preempt is None else preempt.inputs(),
             )
             if sample:
                 placements, consumed = placements
+            if preempt is not None:
+                placements, pre_node, victims = placements
+                self.last_preempt = (np.asarray(pre_node), np.asarray(victims))
             out = np.asarray(placements)  # blocks on device completion
             from ..obs import profile
 

@@ -46,6 +46,10 @@ Deviations (documented, deliberate):
   the reference deletes them from the fake cluster and the preemptor
   is still reported failed by the serial handshake. See
   scheduler/core.py.
+
+The tpu engine runs this same cycle inside its scan where victims can
+affect a preemptor only through NodeResourcesFit and the pod count
+(ops/preempt.py); elsewhere it escapes to the functions below.
 """
 
 from __future__ import annotations
@@ -201,6 +205,21 @@ class PreemptionResult:
     victims: List[dict] = field(default_factory=list)
 
 
+def _pdb_selects(pdb: dict, pod_ns: str, pod_labels: dict) -> bool:
+    """Whether a PodDisruptionBudget's selector matches a pod of
+    namespace `pod_ns` with (non-empty) `pod_labels`."""
+    if (((pdb.get("metadata") or {}).get("namespace")) or "default") != pod_ns:
+        return False
+    selector = (pdb.get("spec") or {}).get("selector")
+    # nil/empty selector matches nothing (the metav1
+    # LabelSelectorAsSelector empty-selector rule there)
+    if not selector or not (
+        selector.get("matchLabels") or selector.get("matchExpressions")
+    ):
+        return False
+    return lbl.match_labels_selector(selector, pod_labels)
+
+
 def filter_pods_with_pdb_violation(
     pods: List[dict], pdbs: List[dict]
 ) -> Tuple[List[dict], List[dict]]:
@@ -217,17 +236,7 @@ def filter_pods_with_pdb_violation(
         violated = False
         if pod_labels:
             for i, pdb in enumerate(pdbs):
-                pdb_ns = ((pdb.get("metadata") or {}).get("namespace")) or "default"
-                if pdb_ns != pod_ns:
-                    continue
-                selector = (pdb.get("spec") or {}).get("selector")
-                # nil/empty selector matches nothing (the metav1
-                # LabelSelectorAsSelector empty-selector rule there)
-                if not selector or not (
-                    selector.get("matchLabels") or selector.get("matchExpressions")
-                ):
-                    continue
-                if not lbl.match_labels_selector(selector, pod_labels):
+                if not _pdb_selects(pdb, pod_ns, pod_labels):
                     continue
                 disrupted = ((pdb.get("status") or {}).get("disruptedPods")) or {}
                 if meta.get("name") in disrupted:
@@ -237,6 +246,35 @@ def filter_pods_with_pdb_violation(
                     violated = True
         (violating if violated else non_violating).append(pod)
     return violating, non_violating
+
+
+def pdb_matched(pod: dict, pdbs: List[dict]) -> bool:
+    """Whether a PodDisruptionBudget selects the pod (same namespace,
+    non-empty selector): the pods filterPodsWithPDBViolation may count
+    as violating. Ignores disruptedPods and the budget, so it may say
+    True of a pod the serial walk would find non-violating — the
+    device dry run treats such a pod as out of scope (ops/preempt.py),
+    which only costs a serial escape."""
+    if not pdbs:
+        return False
+    meta = pod.get("metadata") or {}
+    pod_labels = meta.get("labels") or {}
+    if not pod_labels:
+        return False
+    pod_ns = meta.get("namespace") or "default"
+    return any(_pdb_selects(pdb, pod_ns, pod_labels) for pdb in pdbs)
+
+
+def victim_out_of_scope(oracle, ns, pod: dict) -> bool:
+    """A committed pod the device dry run may not evict: matched by a
+    PodDisruptionBudget, or holding GPU share or open-local volumes."""
+    from ..models import storage as stor
+
+    return (
+        pdb_matched(pod, oracle.pdbs)
+        or stor.pod_gpu_memory(pod) > 0
+        or oracle._pod_key(pod) in ns.local_allocs
+    )
 
 
 def pick_one_node(candidates: List[Candidate], oracle) -> Optional[Candidate]:
@@ -299,6 +337,10 @@ def select_victims_on_node(oracle, pod: dict, ns, pdbs: List[dict], ctx=None):
         return None
     undo = {}
     removed: List[dict] = []
+    # a reprieve re-inserts at a position recorded while other pods were
+    # out, so the restores alone can permute the node's pods; upstream
+    # dry-runs on a clone, and the node keeps its order
+    order = list(ns.pods)
 
     def key(p):
         m = p.get("metadata") or {}
@@ -311,6 +353,7 @@ def select_victims_on_node(oracle, pod: dict, ns, pdbs: List[dict], ctx=None):
     def restore_all():
         for p in reversed(removed):
             oracle.restore_pod_to_node(ns, p, undo[key(p)])
+        ns.pods[:] = order
 
     for p in list(potential):
         remove(p)

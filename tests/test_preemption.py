@@ -416,13 +416,25 @@ def test_evicting_unannotated_gpu_pod_releases_devices():
 # ---------------------------------------------------- hybrid engine routing
 
 
-def _hybrid_case(extra_cluster_pods=(), n_zero=8):
+# a PodDisruptionBudget over every `app: guarded` pod: victims it
+# selects are out of the device dry run's scope (ops/preempt.py), so
+# their preemptors take the serial escape
+GUARDED_PDB = {
+    "kind": "PodDisruptionBudget",
+    "metadata": {"name": "guarded", "namespace": "default"},
+    "spec": {"selector": {"matchLabels": {"app": "guarded"}}},
+}
+
+
+def _hybrid_case(extra_cluster_pods=(), n_zero=8, guarded=False):
     """4 full 1-cpu nodes (800m victim each), 2 preemptors, n_zero
     50m zero-prio pods: the head preempts, the zero run scans, the
-    deferred victims fail at the end."""
+    deferred victims fail at the end. `guarded` puts the victims under
+    GUARDED_PDB, which sends each preemptor to the serial escape."""
     nodes = [make_fake_node(f"node-{i}", "1", "4Gi") for i in range(4)]
+    opts = [with_labels({"app": "guarded"})] if guarded else []
     victims = [
-        make_fake_pod(f"victim-{i}", "default", "800m", "1Gi", with_priority(0))
+        make_fake_pod(f"victim-{i}", "default", "800m", "1Gi", with_priority(0), *opts)
         for i in range(4)
     ]
     preemptors = [
@@ -433,7 +445,10 @@ def _hybrid_case(extra_cluster_pods=(), n_zero=8):
         make_fake_pod(f"zero-{i}", "default", "50m", "8Mi", with_priority(0))
         for i in range(n_zero)
     ]
-    cluster = _cluster(nodes, pods=victims + list(extra_cluster_pods))
+    cluster = _cluster(
+        nodes, pods=victims + list(extra_cluster_pods),
+        pdbs=[GUARDED_PDB] if guarded else (),
+    )
     return cluster, [_app("a", preemptors + zeros)]
 
 
@@ -461,12 +476,22 @@ def _summary(res):
 
 
 def test_priority_scan_escapes_match_serial_oracle(monkeypatch):
-    # both preemptors fail the scan and pass the PostFilter gates ->
-    # one serial escape each, then the zero bulk rides a single scan:
-    # 3 rounds, 2 escapes, placements/preemptions identical to serial
+    # both preemptors fail the scan and pass the PostFilter gates; the
+    # device dry run preempts for each inside the one scan, which the
+    # zero bulk rides too: 1 round, 0 escapes, placements/preemptions
+    # identical to serial. With the victims under a PDB each preemptor
+    # escapes instead: 3 rounds, 2 escapes, the same result
     from open_simulator_tpu.utils.trace import GLOBAL
 
     cluster, apps = _hybrid_case()
+    serial, tpu, note = _run_both(cluster, apps, 4, monkeypatch)
+    assert note == "priority-scan"
+    assert GLOBAL.notes.get("priority-scan-escapes") == 0
+    assert GLOBAL.notes.get("priority-scan-rounds") == 1
+    assert _summary(serial) == _summary(tpu)
+    assert serial.preemptions
+
+    cluster, apps = _hybrid_case(guarded=True)
     serial, tpu, note = _run_both(cluster, apps, 4, monkeypatch)
     assert note == "priority-scan"
     assert GLOBAL.notes.get("priority-scan-escapes") == 2
@@ -489,15 +514,16 @@ def test_priority_scan_negative_commit_keeps_bulk_on_scan(monkeypatch):
     cluster, apps = _hybrid_case(extra_cluster_pods=[neg])
     serial, tpu, note = _run_both(cluster, apps, 4, monkeypatch)
     assert note == "priority-scan"
-    assert GLOBAL.notes.get("priority-scan-escapes") == 2  # the preemptors
+    # the preemptors preempt on the device: no escape at all
+    assert GLOBAL.notes.get("priority-scan-escapes") == 0
     assert _summary(serial) == _summary(tpu)
 
 
 def test_priority_scan_zero_pod_escapes_to_preempt_negative(monkeypatch):
-    # the case that MUST escape: a zero-priority pod fails while a
+    # the case that MUST preempt: a zero-priority pod fails while a
     # negative-priority pod is committed (PostFilter gate 0 > -5), and
-    # the serial escape preempts it — exact serial semantics through
-    # the scan path
+    # the device dry run preempts it inside the scan — exact serial
+    # semantics through the scan path, with no escape
     from open_simulator_tpu.scheduler import core as core_mod
     from open_simulator_tpu.utils.trace import GLOBAL
 
@@ -515,7 +541,7 @@ def test_priority_scan_zero_pod_escapes_to_preempt_negative(monkeypatch):
     GLOBAL.reset()
     tpu = simulate(cluster, apps, engine="tpu")
     assert GLOBAL.notes.get("engine") == "priority-scan"
-    assert GLOBAL.notes.get("priority-scan-escapes") >= 1
+    assert GLOBAL.notes.get("priority-scan-escapes") == 0
     assert any(ev.victim["metadata"]["name"] == "neg" for ev in tpu.preemptions)
     assert _summary(serial) == _summary(tpu)
 
@@ -614,12 +640,13 @@ def test_priority_scan_never_policy_fails_in_scan_without_escape(monkeypatch):
 
 def test_priority_scan_escape_cap_finishes_serially(monkeypatch):
     # past MAX_SCAN_ESCAPES the engine stops rescanning and hands the
-    # remainder to the serial oracle in one pass — still exact
+    # remainder to the serial oracle in one pass — still exact (the
+    # victims are under a PDB, so each preemptor escapes)
     from open_simulator_tpu.scheduler import core as core_mod
     from open_simulator_tpu.utils.trace import GLOBAL
 
     monkeypatch.setattr(core_mod, "MAX_SCAN_ESCAPES", 1)
-    cluster, apps = _hybrid_case()
+    cluster, apps = _hybrid_case(guarded=True)
     serial, tpu, note = _run_both(cluster, apps, 4, monkeypatch)
     assert note == "priority-scan"
     assert GLOBAL.notes.get("priority-scan-escapes") == 1
@@ -806,14 +833,17 @@ def test_priority_scan_escape_cap_serial_tail_matches_oracle(monkeypatch):
     (core._schedule_pods_priority). The tail takes the remaining batch
     in queue order, and the deferred victims still run after it, so
     placements, unscheduled reasons, and preemptions must stay
-    placement-for-placement identical to the pure serial oracle."""
+    placement-for-placement identical to the pure serial oracle. The
+    victims are under a PDB, out of the device dry run's scope, so
+    every preemptor escapes."""
     from open_simulator_tpu.scheduler import core as core_mod
     from open_simulator_tpu.utils.trace import GLOBAL
 
     n = core_mod.MAX_SCAN_ESCAPES + 4  # 20 preempting failures > cap 16
     nodes = [make_fake_node(f"node-{i}", "1", "4Gi") for i in range(n)]
     victims = [
-        make_fake_pod(f"victim-{i}", "default", "800m", "1Gi", with_priority(0))
+        make_fake_pod(f"victim-{i}", "default", "800m", "1Gi", with_priority(0),
+                      with_labels({"app": "guarded"}))
         for i in range(n)
     ]
     for i, v in enumerate(victims):
@@ -826,7 +856,7 @@ def test_priority_scan_escape_cap_serial_tail_matches_oracle(monkeypatch):
         make_fake_pod(f"zero-{i}", "default", "50m", "8Mi", with_priority(0))
         for i in range(8)
     ]
-    cluster = _cluster(nodes, pods=victims)
+    cluster = _cluster(nodes, pods=victims, pdbs=[GUARDED_PDB])
     apps = [_app("a", preemptors + zeros)]
     serial, tpu, note = _run_both(cluster, apps, 4, monkeypatch)
     assert note == "priority-scan"

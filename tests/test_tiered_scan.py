@@ -24,12 +24,23 @@ from open_simulator_tpu.testing import (
 )
 
 
-def _cluster(nodes, pods=(), priority_classes=()):
+def _cluster(nodes, pods=(), priority_classes=(), pdbs=()):
     c = ResourceTypes()
     c.nodes = list(nodes)
     c.pods = list(pods)
     c.priority_classes = list(priority_classes)
+    c.pod_disruption_budgets = list(pdbs)
     return c
+
+
+# a PodDisruptionBudget over the stress case's victims: they are out of
+# the device dry run's scope (ops/preempt.py), so every preemptor takes
+# the serial escape these tests are about
+VICTIM_PDB = {
+    "kind": "PodDisruptionBudget",
+    "metadata": {"name": "victims", "namespace": "default"},
+    "spec": {"selector": {"matchLabels": {"role": "victim"}}},
+}
 
 
 def _app(name, pods):
@@ -61,7 +72,8 @@ def _tier_stress_case(n_nodes=6, n_extra_pre=3, n_zero=8):
     nodes = [make_fake_node(f"node-{i}", "1", "4Gi") for i in range(n_nodes)]
     victims = []
     for i in range(n_nodes):
-        v = make_fake_pod(f"victim-{i}", "default", "800m", "1Gi", with_priority(0))
+        v = make_fake_pod(f"victim-{i}", "default", "800m", "1Gi", with_priority(0),
+                          with_labels({"role": "victim"}))
         v["spec"]["nodeName"] = f"node-{i}"
         victims.append(v)
     pres = [
@@ -90,7 +102,8 @@ def test_tier_stress_across_escape_cap_matches_serial_oracle(monkeypatch):
     def build():
         nodes, victims, pres, zeros = _tier_stress_case()
         return (
-            _cluster(nodes, pods=[dict(v, spec=dict(v["spec"])) for v in victims]),
+            _cluster(nodes, pods=[dict(v, spec=dict(v["spec"])) for v in victims],
+                     pdbs=[VICTIM_PDB]),
             [_app("a", pres + zeros)],
         )
 
@@ -120,7 +133,8 @@ def test_tier_stress_below_cap_matches_serial_oracle(monkeypatch):
     def build():
         nodes, victims, pres, zeros = _tier_stress_case()
         return (
-            _cluster(nodes, pods=[dict(v, spec=dict(v["spec"])) for v in victims]),
+            _cluster(nodes, pods=[dict(v, spec=dict(v["spec"])) for v in victims],
+                     pdbs=[VICTIM_PDB]),
             [_app("a", pres + zeros)],
         )
 

@@ -83,6 +83,41 @@ def _capacity_plan():
     return sweep._pallas_plan, len(sweep.pods)
 
 
+def _preempt_plan():
+    """PreemptionBasic 5000Nodes' app batch: 5,000 priority-10 pods of
+    3000m over 5,000 nodes, the dry run's slots at their real width
+    (ops/preempt.table_slots gives 8 there; the content does not change
+    the program)."""
+    from open_simulator_tpu.ops import pallas_scan
+    from open_simulator_tpu.ops.encode import (
+        encode_batch,
+        encode_cluster,
+        encode_dynamic,
+        features_of_batch,
+    )
+    from open_simulator_tpu.ops.preempt import _FIELDS
+    from open_simulator_tpu.scheduler.oracle import Oracle
+    from open_simulator_tpu.testing import make_fake_node, make_fake_pod, with_priority
+
+    nodes = [make_fake_node(f"node-{i}", "4", "32Gi") for i in range(5000)]
+    pods = [make_fake_pod(f"high-{i}", "default", "3000m", "500Mi", with_priority(10))
+            for i in range(5000)]
+    oracle = Oracle(nodes)
+    cluster = encode_cluster(oracle)
+    batch = encode_batch(oracle, cluster, pods)
+    dyn = encode_dynamic(oracle, cluster)
+    k, n = 8, len(nodes)
+    table = {f: np.zeros((k, n), np.int64) for f in _FIELDS}
+    table.update(valid=np.zeros((k, n), bool), hard=np.zeros((k, n), bool))
+    plan = pallas_scan.build_plan(
+        cluster, batch, dyn, features_of_batch(cluster, batch)._replace(preempt=True),
+        preempt=(table, 1, np.full(len(pods), 10), np.ones(len(pods), bool),
+                 np.zeros(len(pods), bool)),
+    )
+    assert plan is not None and plan.pre.k == k, pallas_scan.last_reject()
+    return plan, len(pods)
+
+
 LAYOUTS = {
     # the flagship: 100k pods over 10k nodes + the new-node padding
     "capacity-100k": (_capacity_plan, "pallas"),
@@ -102,6 +137,7 @@ LAYOUTS = {
     "gpushare-1k": (
         lambda: _plan(*bench.build_gpushare_scenario()), "pallas"
     ),
+    "preempt-5k": (_preempt_plan, "pallas"),
 }
 
 

@@ -26,11 +26,13 @@ import copy
 import hashlib
 import itertools
 import json
+import marshal
 from typing import Optional
 
 from . import labels as lbl
 from .validation import validate_pod_name
 from ..utils.quantity import q_value
+from ..utils.trace import COUNTERS
 
 # pkg/type/const.go
 ANNO_WORKLOAD_KIND = "simon/workload-kind"
@@ -330,13 +332,11 @@ def _set_storage_annotation(pods: list, volume_claim_templates: list):
 
 
 # raw-pod -> intern-key memo: planners and benches expand the SAME
-# decoded pod dicts once per simulate() call, and the sort-keyed
-# json.dumps content key below is ~60% of warm re-expansion wall-clock
-# at 20k bare pods. Keyed on the raw pod's identity — the entry holds
-# a strong ref to the pod, so a key hit proves identity (the
-# utils/memo.py contract; decoded inputs are read-only after load).
-# The sentinel marks non-JSON-serializable pods that must take the
-# full per-pod path every time.
+# decoded pod dicts once per simulate() call. Keyed on the raw pod's
+# identity — the entry holds a strong ref to the pod, so a key hit
+# proves identity (the utils/memo.py contract; decoded inputs are
+# read-only after load). The sentinel marks non-JSON-serializable pods
+# that must take the full per-pod path every time.
 _POD_KEY_CACHE: dict = {}
 _POD_KEY_CACHE_MAX = 1 << 17
 _UNSERIALIZABLE = object()
@@ -351,25 +351,53 @@ def _register_pod_key_cache():
 _register_pod_key_cache()
 
 
-def _pod_intern_key(pod: dict):
+def _bound_node_name(pod: dict):
+    """A bound pod's spec.nodeName (a non-empty string), else None."""
+    spec = pod.get("spec")
+    name = spec.get("nodeName") if isinstance(spec, dict) else None
+    return name if isinstance(name, str) and name else None
+
+
+def _pod_intern_key(pod: dict, interned: dict):
+    """The pod's group key in `interned` (pod_from_pod's per-batch
+    dict): its content as sort-keyed JSON, or _UNSERIALIZABLE."""
     hit = _POD_KEY_CACHE.get(id(pod))
     if hit is not None:
         return hit[1]
     meta = pod.get("metadata") or {}
+    rest = {k: v for k, v in pod.items() if k != "metadata"}
+    bound = _bound_node_name(pod) is not None
+    if bound:
+        rest["spec"] = {k: v for k, v in pod["spec"].items() if k != "nodeName"}
+    # everything except metadata.name and the VALUE of a bound pod's
+    # spec.nodeName participates in the key, so a clone can only differ
+    # from its first by name and node (pod_from_pod stamps both per
+    # pod) — generateName, apiVersion/kind, status etc. are all shared
+    # content. Whether the pod is bound stays in the key: bound and
+    # loose pods of one template are two groups.
+    content = {
+        "metadata": {k: v for k, v in meta.items() if k != "name"},
+        "rest": rest,
+        "bound": bound,
+    }
+    # `interned` also maps the content's marshal bytes to its key.
+    # marshal format 2 writes no back-references, so the bytes depend
+    # on content alone; it is type-exact (1, True and 1.0 give three
+    # byte strings) and refuses all but the built-in types, so equal
+    # bytes prove equal JSON: the sort-keyed json.dumps runs once per
+    # distinct content, not once per pod
     try:
-        # everything except metadata.name participates in the key,
-        # so a clone can only differ from its first by name —
-        # generateName, apiVersion/kind, status etc. are all
-        # shared content
-        key = json.dumps(
-            {
-                "metadata": {k: v for k, v in meta.items() if k != "name"},
-                "rest": {k: v for k, v in pod.items() if k != "metadata"},
-            },
-            sort_keys=True,
-        )
-    except (TypeError, ValueError):
-        key = _UNSERIALIZABLE
+        raw = marshal.dumps(content, 2)
+    except ValueError:
+        raw = None
+    key = interned.get(raw) if raw is not None else None
+    if key is None:
+        try:
+            key = json.dumps(content, sort_keys=True)
+        except (TypeError, ValueError):
+            key = _UNSERIALIZABLE
+        if raw is not None:
+            interned[raw] = key
     if len(_POD_KEY_CACHE) >= _POD_KEY_CACHE_MAX:
         _POD_KEY_CACHE.clear()
     _POD_KEY_CACHE[id(pod)] = (pod, key)
@@ -397,11 +425,14 @@ def _validate_pod_name_cached(pod: dict) -> None:
 class ExpandIndex:
     """Group index emitted alongside workload expansion: pods of one
     group are clones of one content-identical template — same spec,
-    labels, annotations content, nodeName; everything but
-    metadata.name — so queue-sort keys, effective priorities, encode
-    class keys, and pin targets resolve ONCE per group and broadcast
-    by numpy indexing instead of per-pod Python passes
+    labels, annotations content; everything but metadata.name and the
+    node a bound pod names — so queue-sort keys, effective priorities
+    and encode class keys resolve ONCE per group and broadcast by
+    numpy indexing instead of per-pod Python passes
     (scheduler/core.py schedule_app, ops/encode.py encode_batch).
+    Whether a pod is bound (a non-empty spec.nodeName) is group
+    content; the node it names is per-pod data, read from the pod
+    itself (ops/encode.py group_pins).
 
     `group_of[i]` is the group of the i-th expanded pod, `firsts[g]`
     a representative pod of group g (one of the expanded pods)."""
@@ -427,24 +458,27 @@ class ExpandIndex:
 def pod_from_pod(pod: dict, _interned: Optional[dict] = None, index=None) -> dict:
     """MakeValidPod for a bare Pod resource. With `_interned` (a
     per-batch dict the caller threads through), raw pods whose content
-    — minus name/generateName — is identical sanitize ONCE and clone
-    like workload-template replicas: shared sanitized spec and labels
-    (content-equal by key construction; the only post-expansion label
-    write stamps the same app-name for every pod), per-pod annotations
-    (the GPU binder writes a per-pod device index) and status (the
-    binder writes phase). A 20k-pod app built from a handful of pod
-    shapes costs a handful of deepcopy+validation passes instead of
-    20k, and the shared spec objects let the encode class-key memo hit
-    by identity (ops/encode.py). Non-JSON-serializable input falls
-    back to the full per-pod path. `index` (an ExpandIndex) records
-    the pod's content group."""
+    — minus name and a bound pod's nodeName — is identical sanitize
+    ONCE and clone like workload-template replicas: shared sanitized
+    spec content and labels (content-equal by key construction; the
+    only post-expansion label write stamps the same app-name for every
+    pod), per-pod spec.nodeName (stamped into the clone's own spec
+    dict), annotations (the GPU binder writes a per-pod device index)
+    and status (the binder writes phase). A 20k-pod app or running
+    cluster built from a handful of pod shapes costs a handful of
+    deepcopy+validation passes instead of 20k, and the shared spec
+    objects let the encode class-key memo hit by identity
+    (ops/encode.py). Validation reads no nodeName, so the first's full
+    validation covers its bound clones too. Non-JSON-serializable input
+    falls back to the full per-pod path. `index` (an ExpandIndex)
+    records the pod's content group."""
     if _interned is None:
         pod = make_valid_pod(pod)
         if index is not None:
             index.mark_group(pod, 1)
         return pod
     meta = pod.get("metadata") or {}
-    key = _pod_intern_key(pod)
+    key = _pod_intern_key(pod, _interned)
     if key is _UNSERIALIZABLE:
         pod = make_valid_pod(pod)
         if index is not None:
@@ -475,7 +509,16 @@ def pod_from_pod(pod: dict, _interned: Optional[dict] = None, index=None) -> dic
     clone = dict(base)
     clone["metadata"] = clone_meta
     clone["spec"] = dict(fspec)
-    if fstatus is not None:
+    node_name = _bound_node_name(pod)
+    if node_name is not None:
+        # a bound group: whether a pod is bound is group content, the
+        # node it names is its own
+        clone["spec"]["nodeName"] = node_name
+        COUNTERS.inc("expand_bound_clones_total")
+    if isinstance(fstatus, dict):
+        # the binder writes only the top-level phase, per pod
+        clone["status"] = dict(fstatus)
+    elif fstatus is not None:
         clone["status"] = copy.deepcopy(fstatus)
     if clone_meta.get("name") or not clone_meta.get("generateName"):
         # name present: format-validate it; name AND generateName both

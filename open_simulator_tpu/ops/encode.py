@@ -509,6 +509,31 @@ def _image_scores_by_profile(
     return out
 
 
+def group_pins(pods: List[dict], groups, node_index: dict, unknown: int = -1):
+    """Each pod's spec.nodeName pin as a node index (int32 [P]): -1 for
+    a loose pod, `unknown` for a pod bound to a node not in
+    `node_index`. `groups` is the (group_of, firsts) content-group
+    index (workloads.ExpandIndex): whether a pod is bound is group
+    content, so only the pods of bound groups are read, each for the
+    node it names itself."""
+    group_of, firsts = groups
+    out = np.full(len(pods), -1, dtype=np.int32)
+    if not len(pods):
+        return out
+    g_bound = np.fromiter(
+        (bool((f.get("spec") or {}).get("nodeName")) for f in firsts),
+        dtype=bool, count=len(firsts),
+    )
+    idx = np.flatnonzero(g_bound[group_of])
+    if len(idx):
+        get = node_index.get
+        out[idx] = np.fromiter(
+            (get((pods[i].get("spec") or {})["nodeName"], unknown) for i in idx.tolist()),
+            dtype=np.int32, count=len(idx),
+        )
+    return out
+
+
 def encode_batch(
     oracle: Oracle, cluster: ClusterStatic, pods: List[dict], groups=None
 ) -> PodBatch:
@@ -516,10 +541,11 @@ def encode_batch(
 
     `groups` is the optional (group_of, firsts) content-group index
     from workload expansion (workloads.ExpandIndex): group members are
-    content-identical except metadata.name, so the class key, host
-    ports, and pin target resolve once per GROUP and broadcast to pods
-    by numpy indexing — the class-dedup loop drops from O(pods) dict
-    work to O(groups).
+    content-identical except metadata.name and the node a bound pod
+    names, so the class key and host ports resolve once per GROUP and
+    broadcast to pods by numpy indexing — the class-dedup loop drops
+    from O(pods) dict work to O(groups) — and the pins are one pass
+    over the bound groups' pods (group_pins).
 
     Classes are built from pod content alone (`_class_key`): the
     nodeName pin is per-pod data in `pinned_node`, never class content,
@@ -554,23 +580,17 @@ def encode_batch(
         group_of, firsts = groups
         ng = len(firsts)
         g2c = np.zeros(ng, dtype=np.int32)
-        g_pin = np.full(ng, -1, dtype=np.int32)
-        node_index = oracle.node_index
         for g_i, first in enumerate(firsts):
             key = _class_key(first)
             if key not in class_ids:
                 class_ids[key] = len(class_pods)
                 class_pods.append(first)
             g2c[g_i] = class_ids[key]
-            node_name = (first.get("spec") or {}).get("nodeName")
-            if node_name:
-                g_pin[g_i] = node_index.get(node_name, -1)
         if len(pods):
             class_of_pod = g2c[group_of].astype(np.int32, copy=False)
-            pinned = g_pin[group_of].astype(np.int32, copy=False)
         else:
             class_of_pod = np.zeros(0, dtype=np.int32)
-            pinned = np.full(0, -1, dtype=np.int32)
+        pinned = group_pins(pods, groups, oracle.node_index)
     else:
         class_of_pod = np.zeros(len(pods), dtype=np.int32)
         pinned = np.full(len(pods), -1, dtype=np.int32)

@@ -201,8 +201,10 @@ class Simulator:
         # lexicographic sort by (bound-first, -priority | bound-const,
         # tolerations-is-None, nodeSelector-is-None, arrival), and
         # every key is a per-GROUP constant (ExpandIndex: group members
-        # are content-identical except name), so the whole ordering is
-        # a handful of per-group resolutions plus one np.lexsort —
+        # are content-identical except name and the node a bound pod
+        # names; whether it is bound is group content), so the whole
+        # ordering is a handful of per-group resolutions plus one
+        # np.lexsort —
         # replacing the closure-keyed per-pod sorts of the
         # dense-priority cliff. The priority key applies only when a
         # priority signal exists, so the no-priority case keeps the
@@ -628,6 +630,7 @@ class Simulator:
         import numpy as np
 
         from .engine import TpuEngine
+        from ..ops.encode import group_pins
         from ..ops.preempt import ESCAPE
         from ..utils.trace import phase
 
@@ -641,18 +644,10 @@ class Simulator:
             # pos_of maps orig index -> batch position (-1 dangling)
             node_index = self.oracle.node_index
             if groups is not None:
-                # dangling is a per-GROUP fact (nodeName is group
-                # content), so the mask is one numpy gather
+                # whether a pod is bound is group content, the node it
+                # names is its own: one pass over the bound groups' pods
                 group_of, firsts = groups
-                g_dangle = np.fromiter(
-                    (
-                        bool((f.get("spec") or {}).get("nodeName"))
-                        and (f.get("spec") or {})["nodeName"] not in node_index
-                        for f in firsts
-                    ),
-                    dtype=bool, count=len(firsts),
-                )
-                dang = g_dangle[group_of] if p else g_dangle[:0]
+                dang = group_pins(pods, groups, node_index, unknown=-2) == -2
                 if dang.any():
                     bidx = np.flatnonzero(~dang)
                     pos_of = np.full(p, -1, dtype=np.int64)

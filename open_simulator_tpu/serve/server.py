@@ -555,6 +555,13 @@ def _observatory_lines(snap: dict) -> List[str]:
         "class content).",
         counts.get("encode_pinned_pods_total", 0),
     )
+    # -- workload expansion (models/workloads.py pod_from_pod)
+    metric(
+        "simon_expand_bound_clones_total", "counter",
+        "Bound bare pods expanded as clones of their template's first "
+        "pod instead of a full validation each.",
+        counts.get("expand_bound_clones_total", 0),
+    )
     metric(
         "simon_jax_cost_flops_dispatched_total", "counter",
         "FLOPs itemized across every AOT dispatch.",

@@ -282,7 +282,8 @@ def test_commit_simple_bulk_equals_per_pod_commits():
 def test_expand_index_groups_are_content_identical():
     """ExpandIndex invariant the whole tiered path rests on: group
     members match their group's first on everything but
-    metadata.name."""
+    metadata.name and the node a bound pod names (whether it is bound
+    is group content)."""
     import copy
     import json
 
@@ -293,8 +294,11 @@ def test_expand_index_groups_are_content_identical():
     for i in range(12):
         p = make_fake_pod(f"raw-{i}", "default", "100m", "64Mi")
         p = copy.deepcopy(p)
+        p["spec"]["containers"][0]["image"] = "img"
         if i % 3 == 0:
             p["spec"]["priority"] = 1000
+        if i % 4 == 1:
+            p["spec"]["nodeName"] = f"node-{i}"
         raws.append(p)
     res.pods = raws
     res.deployments = [
@@ -320,9 +324,13 @@ def test_expand_index_groups_are_content_identical():
 
     def content(pod):
         d = {k: v for k, v in pod.items() if k != "metadata"}
+        d["spec"] = {k: v for k, v in pod["spec"].items() if k != "nodeName"}
+        d["bound"] = bool(pod["spec"].get("nodeName"))
         m = {k: v for k, v in (pod.get("metadata") or {}).items() if k != "name"}
         return json.dumps({"m": m, "rest": d}, sort_keys=True, default=str)
 
+    # bound and loose pods of each priority: four bare-pod groups
+    assert len(index.firsts) == 5
     for pod, gid in zip(pods, index.group_of):
         assert content(pod) == content(index.firsts[gid])
         # app-name label stamped through the shared labels dict

@@ -230,9 +230,9 @@ def build_affinity_scenario(n_nodes=2000, replicas=20):
     `all` scenario also runs it at 10k nodes x 10k pods (replicas=100)
     to record the BASELINE "pods scheduled/sec at 10k nodes" figure on
     the term machinery."""
-    from open_simulator_tpu.models import workloads as wl
     from open_simulator_tpu.models.decode import ResourceTypes
-    from open_simulator_tpu.scheduler.core import _sort_app_pods
+    from open_simulator_tpu.scheduler.core import AppResource
+    from open_simulator_tpu.scheduler.queues import expand_apps
     from open_simulator_tpu.testing import build_affinity_stress
 
     nodes, stss = build_affinity_stress(
@@ -240,7 +240,7 @@ def build_affinity_scenario(n_nodes=2000, replicas=20):
     )
     res = ResourceTypes()
     res.stateful_sets = stss
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("stress", res, nodes))
+    pods = expand_apps([AppResource("stress", res)], nodes)[0]
     return nodes, pods
 
 
@@ -1691,7 +1691,6 @@ def run_conformance_fuzz(n_nodes=1000, n_pods=2000, seed=0) -> dict:
     fresh on-device conformance pass."""
     import numpy as np
 
-    from open_simulator_tpu.models import workloads as wl
     from open_simulator_tpu.models.decode import ResourceTypes
     from open_simulator_tpu.ops import pallas_scan
     from open_simulator_tpu.ops import scan as scan_ops
@@ -1703,7 +1702,8 @@ def run_conformance_fuzz(n_nodes=1000, n_pods=2000, seed=0) -> dict:
         to_scan_static,
         to_scan_state,
     )
-    from open_simulator_tpu.scheduler.core import _sort_app_pods
+    from open_simulator_tpu.scheduler.core import AppResource
+    from open_simulator_tpu.scheduler.queues import expand_apps
     from open_simulator_tpu.scheduler.oracle import Oracle
     from open_simulator_tpu.testing import build_affinity_stress
 
@@ -1713,7 +1713,7 @@ def run_conformance_fuzz(n_nodes=1000, n_pods=2000, seed=0) -> dict:
     )
     res = ResourceTypes()
     res.stateful_sets = stss
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("fuzz", res, nodes))
+    pods = expand_apps([AppResource("fuzz", res)], nodes)[0]
     # mix in the non-term feature surface: ports, scalars, pins, and
     # open-local storage (r5: the storage block rides the kernel too)
     import json as _json
@@ -1871,7 +1871,6 @@ def _gpu_conformance_fuzz(seed=0, n_nodes=500, n_pods=1500) -> dict:
     import jax.numpy as jnp
     import numpy as np
 
-    from open_simulator_tpu.models import workloads as wl
     from open_simulator_tpu.models.decode import ResourceTypes
     from open_simulator_tpu.ops import pallas_scan
     from open_simulator_tpu.ops import scan as scan_ops
@@ -1883,7 +1882,8 @@ def _gpu_conformance_fuzz(seed=0, n_nodes=500, n_pods=1500) -> dict:
         to_scan_static,
         to_scan_state,
     )
-    from open_simulator_tpu.scheduler.core import _sort_app_pods
+    from open_simulator_tpu.scheduler.core import AppResource
+    from open_simulator_tpu.scheduler.queues import expand_apps
     from open_simulator_tpu.scheduler.oracle import Oracle
     from open_simulator_tpu.testing import build_affinity_stress, with_node_gpu
 
@@ -1895,7 +1895,7 @@ def _gpu_conformance_fuzz(seed=0, n_nodes=500, n_pods=1500) -> dict:
         with_node_gpu(4, "32")(node)
     res = ResourceTypes()
     res.stateful_sets = stss
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("gfuzz", res, nodes))
+    pods = expand_apps([AppResource("gfuzz", res)], nodes)[0]
     for i, pod in enumerate(pods[:n_pods]):
         if rng.randint(0, 5) != 0:
             continue

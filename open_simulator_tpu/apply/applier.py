@@ -566,20 +566,9 @@ def probe_plan_multi(
         # expanded pods get their OWN shallow copies from the still-
         # pristine originals before ANY spec replays, so every spec's
         # ApplyResult embeds dicts no later replay rewrites (review r5)
-        def own_pod(p):
-            q = dict(p)
-            q["spec"] = dict(p["spec"])
-            meta = dict(p.get("metadata") or {})
-            if meta.get("annotations") is not None:
-                meta["annotations"] = dict(meta["annotations"])
-            q["metadata"] = meta
-            if isinstance(q.get("status"), dict):
-                q["status"] = dict(q["status"])
-            return q
-
         for sweep, _, _ in jobs:
             if sweep.pods_shared:
-                sweep.pods = [own_pod(p) for p in sweep.pods]
+                sweep.pods = [wl.own_pod(p) for p in sweep.pods]
         return [
             _finish_plan(sweep, best, max_count, extended_resources)
             for (sweep, _, _), best in zip(jobs, bests)

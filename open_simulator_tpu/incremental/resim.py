@@ -46,6 +46,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..models import workloads as wl
+from ..models.workloads import own_pod
 from ..runtime import inject as _inject
 from ..utils.trace import COUNTERS
 
@@ -60,21 +62,6 @@ _CODE_NAMES = {
     S_BULK: "bulk", S_PINNED: "pinned", S_FAILED: "failed",
     S_DANGLING: "dangling", S_SIDE: "side-effect",
 }
-
-
-def own_pod(p: dict) -> dict:
-    """Shallow-clone the mutation surface of a pod dict (bind writes
-    spec.nodeName / status.phase / metadata.annotations) — the serve
-    Session idiom: roster dicts stay pristine for later encodes."""
-    q = dict(p)
-    q["spec"] = dict(p.get("spec") or {})
-    meta = dict(p.get("metadata") or {})
-    if meta.get("annotations") is not None:
-        meta["annotations"] = dict(meta["annotations"])
-    q["metadata"] = meta
-    if isinstance(q.get("status"), dict):
-        q["status"] = dict(q["status"])
-    return q
 
 
 @dataclass
@@ -263,7 +250,9 @@ class CommittedScan:
         suffix = [own_pod(p) for p in roster[start:]]
         sim = Simulator(engine="tpu")
         sim.oracle = self.oracle
-        result = sim._schedule_pods(suffix, build_status=False)
+        result = sim._schedule_pods(
+            suffix, wl.singleton_groups(suffix), build_status=False
+        )
         if result.preemptions or self.oracle.saw_priority:
             self._ordering_coupled = True
         COUNTERS.inc("incremental_suffix_pods_total", len(suffix))

@@ -29,6 +29,8 @@ import json
 import marshal
 from typing import Optional
 
+import numpy as np
+
 from . import labels as lbl
 from .validation import validate_pod_name
 from ..utils.quantity import q_value
@@ -429,7 +431,7 @@ class ExpandIndex:
     node a bound pod names — so queue-sort keys, effective priorities
     and encode class keys resolve ONCE per group and broadcast by
     numpy indexing instead of per-pod Python passes
-    (scheduler/core.py schedule_app, ops/encode.py encode_batch).
+    (scheduler/queues.py queue_order, ops/encode.py encode_batch).
     Whether a pod is bound (a non-empty spec.nodeName) is group
     content; the node it names is per-pod data, read from the pod
     itself (ops/encode.py group_pins).
@@ -454,80 +456,85 @@ class ExpandIndex:
         gid = self.new_group(first)
         self.group_of.extend([gid] * count)
 
+    def groups(self) -> tuple:
+        """The (group_of, firsts) pair a batch consumer reads
+        (ops/encode.py encode_batch, scheduler/core.py)."""
+        return np.asarray(self.group_of, dtype=np.int64), self.firsts
 
-def pod_from_pod(pod: dict, _interned: Optional[dict] = None, index=None) -> dict:
-    """MakeValidPod for a bare Pod resource. With `_interned` (a
-    per-batch dict the caller threads through), raw pods whose content
-    — minus name and a bound pod's nodeName — is identical sanitize
-    ONCE and clone like workload-template replicas: shared sanitized
-    spec content and labels (content-equal by key construction; the
-    only post-expansion label write stamps the same app-name for every
-    pod), per-pod spec.nodeName (stamped into the clone's own spec
-    dict), annotations (the GPU binder writes a per-pod device index)
-    and status (the binder writes phase). A 20k-pod app or running
-    cluster built from a handful of pod shapes costs a handful of
-    deepcopy+validation passes instead of 20k, and the shared spec
-    objects let the encode class-key memo hit by identity
-    (ops/encode.py). Validation reads no nodeName, so the first's full
-    validation covers its bound clones too. Non-JSON-serializable input
-    falls back to the full per-pod path. `index` (an ExpandIndex)
-    records the pod's content group."""
-    if _interned is None:
-        pod = make_valid_pod(pod)
-        if index is not None:
-            index.mark_group(pod, 1)
-        return pod
-    meta = pod.get("metadata") or {}
-    key = _pod_intern_key(pod, _interned)
+
+def singleton_groups(pods: list) -> tuple:
+    """The (group_of, firsts) pair of a batch with no expansion index:
+    one group per pod."""
+    return np.arange(len(pods), dtype=np.int64), pods
+
+
+def join_groups(*parts) -> tuple:
+    """(group_of, firsts) pairs of consecutive pod runs as one pair."""
+    group_of, firsts = [np.zeros(0, dtype=np.int64)], []
+    for g, f in parts:
+        group_of.append(np.asarray(g, dtype=np.int64) + len(firsts))
+        firsts.extend(f)
+    return np.concatenate(group_of), firsts
+
+
+def own_pod(p: dict) -> dict:
+    """Shallow-clone a pod's mutation surface (bind writes
+    spec.nodeName / status.phase / metadata.annotations): the clone can
+    be bound, replayed or re-pinned without touching `p`, whose nested
+    content it otherwise shares."""
+    q = dict(p)
+    q["spec"] = dict(p.get("spec") or {})
+    meta = dict(p.get("metadata") or {})
+    if meta.get("annotations") is not None:
+        meta["annotations"] = dict(meta["annotations"])
+    q["metadata"] = meta
+    if isinstance(q.get("status"), dict):
+        q["status"] = dict(q["status"])
+    return q
+
+
+def pod_from_pod(pod: dict, interned: dict, index: ExpandIndex) -> dict:
+    """MakeValidPod for a bare Pod resource, interned: raw pods whose
+    content — minus name and a bound pod's nodeName — is identical
+    (`interned`, a per-expansion dict) sanitize ONCE and clone like
+    workload-template replicas (own_pod of the group's first: shared
+    sanitized spec content and labels, per-pod spec.nodeName,
+    annotations and status). A 20k-pod app or running cluster built
+    from a handful of pod shapes costs a handful of deepcopy+validation
+    passes instead of 20k, and the shared spec objects let the encode
+    class-key memo hit by identity (ops/encode.py). Validation reads
+    no nodeName, so the first's full validation covers its bound
+    clones too. Non-JSON-serializable input takes the full per-pod
+    path. `index` records the pod's content group."""
+    key = _pod_intern_key(pod, interned)
     if key is _UNSERIALIZABLE:
         pod = make_valid_pod(pod)
-        if index is not None:
-            index.mark_group(pod, 1)
+        index.mark_group(pod, 1)
         return pod
-    entry = _interned.get(key)
+    entry = interned.get(key)
     if entry is None:
         first = make_valid_pod(pod)
-        gid = index.new_group(first) if index is not None else -1
-        fmeta = first["metadata"]
-        # clone template, precomputed once per group: the non-varying
-        # top-level items and the shared sub-dict refs
-        base = {
-            k: v for k, v in first.items() if k not in ("metadata", "spec", "status")
-        }
-        _interned[key] = (
-            first, gid, base, fmeta,
-            fmeta.get("annotations") or {}, first["spec"],
-            first.get("status"),
-        )
-        if index is not None:
-            index.mark(gid)
+        gid = index.new_group(first)
+        interned[key] = (first, gid)
+        index.mark(gid)
         return first
-    first, gid, base, fmeta, fanno, fspec, fstatus = entry
-    clone_meta = dict(fmeta)
-    clone_meta["name"] = meta.get("name", "")
-    clone_meta["annotations"] = dict(fanno)
-    clone = dict(base)
-    clone["metadata"] = clone_meta
-    clone["spec"] = dict(fspec)
+    first, gid = entry
+    clone = own_pod(first)
+    clone["metadata"]["name"] = (pod.get("metadata") or {}).get("name", "")
     node_name = _bound_node_name(pod)
     if node_name is not None:
         # a bound group: whether a pod is bound is group content, the
         # node it names is its own
         clone["spec"]["nodeName"] = node_name
         COUNTERS.inc("expand_bound_clones_total")
-    if isinstance(fstatus, dict):
-        # the binder writes only the top-level phase, per pod
-        clone["status"] = dict(fstatus)
-    elif fstatus is not None:
-        clone["status"] = copy.deepcopy(fstatus)
-    if clone_meta.get("name") or not clone_meta.get("generateName"):
+    meta = clone["metadata"]
+    if meta.get("name") or not meta.get("generateName"):
         # name present: format-validate it; name AND generateName both
         # absent: raise the same required error the full path would.
         # generateName-only clones skip: their generateName is part of
         # the intern key, so the first's full validation covered it
         _validate_pod_name_cached(clone)
-    if index is not None:
-        index.mark(gid)
+    index.mark(gid)
     return clone
 
 
@@ -594,18 +601,17 @@ def pods_from_daemon_set(ds: dict, nodes: list) -> list:
 
 
 def pods_excluding_daemon_sets(resources, index: Optional[ExpandIndex] = None) -> list:
-    """GetValidPodExcludeDaemonSet (pkg/simulator/utils.go:76-136).
-    With `index`, records each pod's content group (ExpandIndex): every
-    `_expand_template` call yields one group (replicas are clones of
-    one validated template), bare pods group by intern key."""
-    pods = []
+    """GetValidPodExcludeDaemonSet (pkg/simulator/utils.go:76-136),
+    recording each pod's content group into `index` (ExpandIndex):
+    every `_expand_template` call yields one group (replicas are clones
+    of one validated template), bare pods group by intern key."""
+    index = ExpandIndex() if index is None else index
     interned: dict = {}
-    for p in resources.pods:
-        pods.append(pod_from_pod(p, _interned=interned, index=index))
+    pods = [pod_from_pod(p, interned, index) for p in resources.pods]
 
     def extend(ps):
         pods.extend(ps)
-        if index is not None and ps:
+        if ps:
             index.mark_group(ps[0], len(ps))
 
     for d in resources.deployments:
@@ -623,30 +629,35 @@ def pods_excluding_daemon_sets(resources, index: Optional[ExpandIndex] = None) -
     return pods
 
 
+def expand_pods(resources, nodes: list, index: Optional[ExpandIndex] = None) -> list:
+    """Every pod a resource set yields, in the reference's order: bare
+    pods and workloads (pods_excluding_daemon_sets), then one pod per
+    eligible node for each DaemonSet. The one expansion of a running
+    cluster (simulate, the plan sweep, serve) and of an app
+    (generate_valid_pods_from_app). Daemonset pods pin per node via
+    matchFields, so each is its own content group in `index`."""
+    index = ExpandIndex() if index is None else index
+    pods = pods_excluding_daemon_sets(resources, index)
+    for ds in resources.daemon_sets:
+        for pod in pods_from_daemon_set(ds, nodes):
+            pods.append(pod)
+            index.mark_group(pod, 1)
+    return pods
+
+
 def generate_valid_pods_from_app(
     app_name: str, resources, nodes: list, index: Optional[ExpandIndex] = None
 ) -> list:
     """GenerateValidPodsFromAppResources (pkg/simulator/utils.go:36-73):
-    regular workloads + per-node daemonset pods, all labelled with the
-    app name. With `index` (ExpandIndex) the app-name label stamps once
+    expand_pods, all labelled with the app name. The label stamps once
     per GROUP — clones share their labels dict with the group's first
     by construction, so the write is identical, minus one pass over
     100k pods."""
-    pods = pods_excluding_daemon_sets(resources, index=index)
-    for ds in resources.daemon_sets:
-        ds_pods = pods_from_daemon_set(ds, nodes)
-        pods.extend(ds_pods)
-        if index is not None:
-            for pod in ds_pods:
-                # daemonset pods pin per node via matchFields — every
-                # pod is its own content group
-                index.mark_group(pod, 1)
-    if index is not None:
-        for first in index.firsts:
-            first["metadata"].setdefault("labels", {})[LABEL_APP_NAME] = app_name
-    else:
-        for pod in pods:
-            pod["metadata"].setdefault("labels", {})[LABEL_APP_NAME] = app_name
+    index = ExpandIndex() if index is None else index
+    g0 = len(index.firsts)
+    pods = expand_pods(resources, nodes, index)
+    for first in index.firsts[g0:]:
+        first["metadata"].setdefault("labels", {})[LABEL_APP_NAME] = app_name
     return pods
 
 

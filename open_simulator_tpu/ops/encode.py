@@ -34,6 +34,7 @@ import numpy as np
 from ..models import labels as lbl
 from ..models import requests as req
 from ..models import storage as stor
+from ..models import workloads as wl
 from ..utils.memo import IdentityMemo, register_cache
 from ..utils.trace import COUNTERS
 from .profiles import freeze as _freeze
@@ -539,18 +540,20 @@ def encode_batch(
 ) -> PodBatch:
     """Build class-deduplicated static tensors for a pod batch.
 
-    `groups` is the optional (group_of, firsts) content-group index
-    from workload expansion (workloads.ExpandIndex): group members are
+    `groups` is the (group_of, firsts) content-group index from
+    workload expansion (workloads.ExpandIndex): group members are
     content-identical except metadata.name and the node a bound pod
     names, so the class key and host ports resolve once per GROUP and
-    broadcast to pods by numpy indexing — the class-dedup loop drops
-    from O(pods) dict work to O(groups) — and the pins are one pass
-    over the bound groups' pods (group_pins).
+    broadcast to pods by numpy indexing — the class-dedup loop is
+    O(groups) dict work, not O(pods) — and the pins are one pass over
+    the bound groups' pods (group_pins). Without one, every pod is its
+    own group (workloads.singleton_groups).
 
     Classes are built from pod content alone (`_class_key`): the
     nodeName pin is per-pod data in `pinned_node`, never class content,
     so bound and loose pods of one template share a class and the
     [U, N] tables grow with templates, not with bound pods."""
+    group_of, firsts = groups or wl.singleton_groups(pods)
     # port vocabulary over batch + existing usage
     vocab: List[tuple] = []
     seen = set()
@@ -559,8 +562,7 @@ def encode_batch(
             if port not in seen:
                 seen.add(port)
                 vocab.append(port)
-    port_scan = pods if groups is None else groups[1]
-    for pod in port_scan:
+    for pod in firsts:
         for port in _pod_host_ports(pod):
             if port not in seen:
                 seen.add(port)
@@ -576,33 +578,15 @@ def encode_batch(
     # class dedup
     class_ids: Dict[str, int] = {}
     class_pods: List[dict] = []
-    if groups is not None:
-        group_of, firsts = groups
-        ng = len(firsts)
-        g2c = np.zeros(ng, dtype=np.int32)
-        for g_i, first in enumerate(firsts):
-            key = _class_key(first)
-            if key not in class_ids:
-                class_ids[key] = len(class_pods)
-                class_pods.append(first)
-            g2c[g_i] = class_ids[key]
-        if len(pods):
-            class_of_pod = g2c[group_of].astype(np.int32, copy=False)
-        else:
-            class_of_pod = np.zeros(0, dtype=np.int32)
-        pinned = group_pins(pods, groups, oracle.node_index)
-    else:
-        class_of_pod = np.zeros(len(pods), dtype=np.int32)
-        pinned = np.full(len(pods), -1, dtype=np.int32)
-        for p_i, pod in enumerate(pods):
-            key = _class_key(pod)
-            if key not in class_ids:
-                class_ids[key] = len(class_pods)
-                class_pods.append(pod)
-            class_of_pod[p_i] = class_ids[key]
-            node_name = (pod.get("spec") or {}).get("nodeName")
-            if node_name:
-                pinned[p_i] = oracle.node_index.get(node_name, -1)
+    g2c = np.zeros(len(firsts), dtype=np.int32)
+    for g_i, first in enumerate(firsts):
+        key = _class_key(first)
+        if key not in class_ids:
+            class_ids[key] = len(class_pods)
+            class_pods.append(first)
+        g2c[g_i] = class_ids[key]
+    class_of_pod = g2c[group_of]
+    pinned = group_pins(pods, (group_of, firsts), oracle.node_index)
 
     u = len(class_pods)
     n = cluster.n

@@ -33,7 +33,7 @@ import numpy as np
 from ..models import workloads as wl
 from ..models.decode import ResourceTypes
 from ..models.validation import InputError
-from ..scheduler.core import AppResource, _sort_app_pods
+from ..scheduler.core import AppResource
 from ..scheduler.oracle import Oracle
 
 from ..runtime.guard import run_chunked, run_laddered
@@ -188,33 +188,28 @@ class CapacitySweep:
             self.oracle = Oracle(padded.nodes)
             if share_pods_from is not None:
                 # expansion (and its priority/plugin checks) shared
-                pods = share_pods_from.pods
+                pods, groups = share_pods_from.pods, share_pods_from.groups
             else:
-                pods: List[dict] = []
-                pods.extend(wl.pods_excluding_daemon_sets(padded))
-                for ds in padded.daemon_sets:
-                    pods.extend(wl.pods_from_daemon_set(ds, padded.nodes))
-                for app in apps:
-                    app_pods = wl.generate_valid_pods_from_app(
-                        app.name, app.resource, padded.nodes
-                    )
-                    if use_greed:
-                        # same ordering the authoritative serial run
-                        # will use (scheduler/core.py schedule_app):
-                        # greed_sort ignores simon new nodes, so
-                        # max-count padding and the per-count serial
-                        # cluster sort pods identically
-                        from ..scheduler.queues import greed_sort
-
-                        app_pods = greed_sort(padded.nodes, app_pods)
-                    pods.extend(_sort_app_pods(app_pods))
                 from ..scheduler.preemption import (
+                    batch_priorities,
                     build_priority_resolver,
-                    pod_uses_priority,
                 )
+                from ..scheduler.queues import expand_apps
 
+                index = wl.ExpandIndex()
+                pods = wl.expand_pods(padded, padded.nodes, index)
                 resolver = build_priority_resolver(cluster.priority_classes)
-                if any(pod_uses_priority(p, resolver) for p in pods):
+                # the order the authoritative serial run uses
+                # (scheduler/core.py schedule_app): greed_sort ignores
+                # simon new nodes, so max-count padding and the
+                # per-count serial cluster sort pods identically. A
+                # custom QueueSort plugin is not honoured here
+                app_pods, app_groups, prios = expand_apps(
+                    apps, padded.nodes, resolver=resolver, use_greed=use_greed
+                )
+                pods.extend(app_pods)
+                groups = wl.join_groups(index.groups(), app_groups)
+                if prios.any() or batch_priorities(index.firsts, resolver).any():
                     raise PrioritySignalError(
                         "workload carries priority/priorityClassName; the "
                         "batched scan has no priority/preemption semantics — "
@@ -229,12 +224,15 @@ class CapacitySweep:
                         "engine (scheduler/core.py falls back automatically)"
                     )
         self.pods = pods
+        # content groups of the expansion: the batch encodes once per
+        # template (ops/encode.py encode_batch)
+        self.groups = groups
         self.n = len(padded.nodes)
         self.n_base = self.n - self.max_count
 
         with phase("sweep/encode"):
             self.cluster_enc = encode_cluster(self.oracle)
-            self.batch = encode_batch(self.oracle, self.cluster_enc, pods)
+            self.batch = encode_batch(self.oracle, self.cluster_enc, pods, groups)
             self.dyn = encode_dynamic(self.oracle, self.cluster_enc)
             self.static = to_scan_static(self.cluster_enc, self.batch)
             self.init = to_scan_state(self.dyn, self.batch)

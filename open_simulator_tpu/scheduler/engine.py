@@ -141,7 +141,8 @@ class TpuEngine:
         in the tracker, simulator.go:221-229). `groups` is the
         (group_of, firsts) content-group index from workload expansion
         (workloads.ExpandIndex) — class keys then resolve once per
-        group instead of once per pod."""
+        group; without one every pod is its own group
+        (ops/encode.py encode_batch)."""
         from ..utils.trace import phase
 
         oracle = self.oracle

@@ -866,8 +866,6 @@ class ServeDaemon:
                 mutates nothing (400); each applied delta journals to
                 the session snapshot (--snapshot), so a restarted
                 daemon can see what its warm state had absorbed."""
-                import copy as _copy
-
                 from ..models import workloads as _wl
                 from ..models.validation import InputError
                 from ..twin import deltas as _dl
@@ -912,7 +910,7 @@ class ServeDaemon:
                                 )
                             names.discard(d.node_name)
                         elif d.kind in (_dl.POD_BIND, _dl.POD_ARRIVE):
-                            _wl.pod_from_pod(_copy.deepcopy(d.pod))
+                            _wl.make_valid_pod(d.pod)
                 except (UnicodeDecodeError, ValueError, InputError) as e:
                     self._send(
                         400,

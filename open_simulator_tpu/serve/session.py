@@ -49,7 +49,6 @@ from ..scheduler.core import (
     NodeStatus,
     SimulateResult,
     UnscheduledPod,
-    _sort_app_pods,
     simulate,
 )
 from ..scheduler.oracle import Oracle
@@ -119,15 +118,6 @@ def result_payload(result: SimulateResult) -> bytes:
     return json.dumps(out, sort_keys=True, separators=(",", ":")).encode()
 
 
-# Shallow-clone of a pod's mutation surface (bind writes spec.nodeName
-# / status.phase / metadata.annotations) so replaying a scenario never
-# pollutes the shared cluster pods or a request's expansion — the next
-# batch re-encodes those dicts and a stale nodeName would read as a
-# pin. ONE definition, shared with the committed-scan machinery: the
-# mutation surface must never diverge between the two replay paths.
-from ..incremental.resim import own_pod as _own_pod  # noqa: E402
-
-
 class Session:
     """One warm cluster + the machinery to answer request batches.
 
@@ -169,16 +159,13 @@ class Session:
         self.delta_reloads = 0
         with phase("serve/session-build"):
             wl.reset_name_counter()
-            pods: List[dict] = []
-            pods.extend(wl.pods_excluding_daemon_sets(cluster))
+            pods = wl.expand_pods(cluster, cluster.nodes)
             # bare cluster pods expand 1:1 and FIRST; delta arrivals
             # insert at the end of that section so warm roster order
             # equals the cold expansion order of the materialized
             # cluster (cluster.pods + deltas, then workloads, then
             # daemonsets)
             self._bare_end = len(cluster.pods)
-            for ds in cluster.daemon_sets:
-                pods.extend(wl.pods_from_daemon_set(ds, cluster.nodes))
             self.cluster_pods = pods
             # every request's app expansion restarts from this state
             self._counter0 = wl.name_counter_state()
@@ -256,19 +243,18 @@ class Session:
 
     # -- expansion ----------------------------------------------------------
 
-    def _expand_request(self, req: WhatIfRequest) -> List[dict]:
-        """Expand one request's apps exactly like a standalone run:
-        counter re-seated to the post-cluster state, apps in order,
-        each app's pods through the affinity/toleration queue sorts
-        (the zero-priority ordering of scheduler/core.schedule_app)."""
+    def _expand_request(self, req: WhatIfRequest):
+        """Expand one request's apps exactly like a standalone run
+        (scheduler/queues.expand_apps, the expansion and queue order of
+        scheduler/core.schedule_app) with the counter re-seated to the
+        post-cluster state. Returns (pods, prios)."""
+        from ..scheduler.queues import expand_apps
+
         wl.set_name_counter(self._counter0)
-        pods: List[dict] = []
-        for app in req.apps:
-            app_pods = wl.generate_valid_pods_from_app(
-                app.name, app.resource, self.cluster.nodes
-            )
-            pods.extend(_sort_app_pods(app_pods))
-        return pods
+        pods, _groups, prios = expand_apps(
+            req.apps, self.cluster.nodes, resolver=self._resolver
+        )
+        return pods, prios
 
     # -- evaluation ---------------------------------------------------------
 
@@ -301,7 +287,7 @@ class Session:
         with phase("serve/expand"):
             for r_i, req in enumerate(reqs):
                 try:
-                    pods = self._expand_request(req)
+                    pods, prios = self._expand_request(req)
                 except (InputError, ValueError, KeyError) as e:
                     replies[r_i] = WhatIfReply(
                         status=400,
@@ -312,9 +298,7 @@ class Session:
                     )
                     continue
                 expanded[r_i] = pods
-                if self.force_serial_reason or any(
-                    self._pod_uses_priority(p, self._resolver) for p in pods
-                ):
+                if self.force_serial_reason or prios.any():
                     replies[r_i] = self._evaluate_serial(
                         req,
                         reason=self.force_serial_reason
@@ -600,7 +584,7 @@ class Session:
         oracle = Oracle([ns.node for ns in committed.oracle.nodes])
         for ns in committed.oracle.nodes:
             for p in ns.pods:
-                oracle.place_existing_pod(_own_pod(p))
+                oracle.place_existing_pod(wl.own_pod(p))
         failed: List[UnscheduledPod] = list(committed.failed)
         for i, pod in scenario_pods:
             pos = int(pos_of[i])
@@ -609,7 +593,7 @@ class Session:
             place = int(placements[pos])
             if place == INACTIVE:  # pragma: no cover - defensive
                 continue
-            pod2 = _own_pod(pod)
+            pod2 = wl.own_pod(pod)
             if (pod.get("spec") or {}).get("nodeName"):
                 oracle.place_existing_pod(pod2)
             elif place < 0:
@@ -632,13 +616,13 @@ class Session:
         scan order — the engine-replay contract of scheduler/engine.py:
         failure reasons read the oracle state of their own step, so
         they match what the standalone run reports. Pods replay as
-        copies (_own_pod): the session's shared dicts stay pristine for
+        copies (wl.own_pod): the session's shared dicts stay pristine for
         the next batch's encode."""
         oracle = Oracle([ns.node for ns in self.oracle.nodes])
         failed: List[UnscheduledPod] = []
         for i, pod in scenario_pods:
             pos = int(pos_of[i])
-            pod2 = _own_pod(pod)
+            pod2 = wl.own_pod(pod)
             if pos < 0:
                 # dangling (unknown spec.nodeName): tracked, never
                 # scheduled, absent from node status — like simulate()
@@ -676,13 +660,13 @@ class Session:
         if base is not None:
             for ns in base.oracle.nodes:
                 for p in ns.pods:
-                    oracle.place_existing_pod(_own_pod(p))
+                    oracle.place_existing_pod(wl.own_pod(p))
         node_index = self.oracle.node_index
         out = np.full(len(batch_idx), INACTIVE, dtype=np.int64)
         for pos, i in enumerate(batch_idx):
             if not active[pos]:
                 continue
-            pod2 = _own_pod(all_pods[i])
+            pod2 = wl.own_pod(all_pods[i])
             if (pod2.get("spec") or {}).get("nodeName"):
                 oracle.place_existing_pod(pod2)
                 out[pos] = node_index[pod2["spec"]["nodeName"]]
@@ -787,7 +771,7 @@ class Session:
             # roster slot moves to the section end — the order a cold
             # reload of the mutated cluster.pods list would expand)
             removed_at = self._remove_roster_pod(delta.pod_key)
-            valid = wl.pod_from_pod(copy.deepcopy(raw))
+            valid = wl.make_valid_pod(raw)
             insert_at = self._bare_end
             self.cluster.pods.append(raw)
             self.cluster_pods.insert(self._bare_end, valid)
@@ -913,10 +897,7 @@ def materialized_state_digest(cluster: ResourceTypes) -> str:
     saved = wl.name_counter_state()
     try:
         wl.reset_name_counter()
-        pods: List[dict] = []
-        pods.extend(wl.pods_excluding_daemon_sets(cluster))
-        for ds in cluster.daemon_sets:
-            pods.extend(wl.pods_from_daemon_set(ds, cluster.nodes))
+        pods = wl.expand_pods(cluster, cluster.nodes)
     finally:
         wl.set_name_counter(saved)
     return config_fingerprint(

@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from ..models.decode import ResourceTypes
+from ..models.workloads import own_pod
 from ..models.validation import InputError
 from ..obs import profile as obs_profile
 from ..obs.explain import EXPLAIN
@@ -52,21 +53,6 @@ from .report import (
 # score-vector rows carried per divergence (disputed nodes are always
 # included on top of this cap)
 MAX_SCORE_ROWS = 16
-
-
-def _own_pod(p: dict) -> dict:
-    """Shallow-clone a pod's mutation surface (bind writes
-    spec.nodeName / status / metadata.annotations) so replaying from an
-    in-memory step list leaves the steps reusable."""
-    q = dict(p)
-    q["spec"] = dict(p.get("spec") or {})
-    meta = dict(p.get("metadata") or {})
-    if meta.get("annotations") is not None:
-        meta["annotations"] = dict(meta["annotations"])
-    q["metadata"] = meta
-    if isinstance(q.get("status"), dict):
-        q["status"] = dict(q["status"])
-    return q
 
 
 def _pod_name(pod: dict) -> str:
@@ -291,7 +277,7 @@ class ShadowReplayer:
             self._apply_delta(op)
         if st.kind != "decision":
             return None
-        pod = _own_pod(st.pod)
+        pod = own_pod(st.pod)
         if (pod.get("spec") or {}).get("nodeName"):
             raise InputError(
                 f"decision step {st.seq} pod {_pod_name(pod)} carries "

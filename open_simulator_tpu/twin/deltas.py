@@ -60,6 +60,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from ..models.validation import InputError
+from ..models.workloads import own_pod
 from ..utils.trace import COUNTERS
 
 NODE_JOIN = "node_join"
@@ -80,21 +81,6 @@ RELOADED = "reloaded"
 def _pod_key(pod: dict) -> Tuple[str, str]:
     meta = (pod or {}).get("metadata") or {}
     return (meta.get("namespace") or "default", meta.get("name", ""))
-
-
-def _own_pod(p: dict) -> dict:
-    """Shallow-clone a pod's mutation surface (bind writes
-    spec.nodeName / status / metadata.annotations) so applying a delta
-    never pollutes the caller's record objects."""
-    q = dict(p)
-    q["spec"] = dict(p.get("spec") or {})
-    meta = dict(p.get("metadata") or {})
-    if meta.get("annotations") is not None:
-        meta["annotations"] = dict(meta["annotations"])
-    q["metadata"] = meta
-    if isinstance(q.get("status"), dict):
-        q["status"] = dict(q["status"])
-    return q
 
 
 @dataclass
@@ -198,7 +184,7 @@ def from_shadow_op(op: dict) -> ClusterDelta:
     if kind == "place_pod":
         pod = op.get("pod") or {}
         node = (pod.get("spec") or {}).get("nodeName") or ""
-        unbound = _own_pod(pod)
+        unbound = own_pod(pod)
         unbound["spec"].pop("nodeName", None)
         return ClusterDelta(kind=POD_BIND, pod=unbound, node_name=node)
     if kind == "evict_pod":
@@ -253,7 +239,7 @@ def deltas_to_events(
             out.append(tev.Event(time=t, kind=tev.POD_ARRIVAL, seq=i,
                                  pod=copy.deepcopy(d.pod)))
         elif d.kind == POD_BIND:
-            pod = _own_pod(d.pod)
+            pod = own_pod(d.pod)
             pod["spec"]["nodeName"] = d.node_name
             out.append(tev.Event(time=t, kind=tev.POD_ARRIVAL, seq=i, pod=pod))
         elif d.kind in (POD_EVICT, POD_DELETE):
@@ -343,7 +329,7 @@ class MirrorApplicator:
         if kind == POD_EVICT:
             return self._evict(delta.pod_key, delta.node_name or None)
         if kind == POD_ARRIVE:
-            self.pending[delta.pod_key] = _own_pod(delta.pod)
+            self.pending[delta.pod_key] = own_pod(delta.pod)
             return APPLIED
         if kind == POD_DELETE:
             if self.pending.pop(delta.pod_key, None) is None:
@@ -369,7 +355,7 @@ class MirrorApplicator:
             # a re-bind of a live key (delete+recreate collapsed into
             # one poll): evict the stale binding first
             self._evict(key, self._bound.get(key))
-        pod = _own_pod(delta.pod)
+        pod = own_pod(delta.pod)
         pod["spec"]["nodeName"] = delta.node_name
         oracle.place_existing_pod(pod)
         self._bound[key] = delta.node_name
@@ -448,7 +434,7 @@ class MirrorApplicator:
         """Track a pod the real scheduler FAILED to place: it exists,
         pending — the population the twin's capacity forecast requeues
         (queries.py)."""
-        self.pending[_pod_key(pod)] = _own_pod(pod)
+        self.pending[_pod_key(pod)] = own_pod(pod)
 
     # -- canonical state ---------------------------------------------------
 
@@ -541,7 +527,7 @@ def materialize(base_nodes: List[dict], deltas: List[ClusterDelta]) -> Materiali
         elif d.kind == POD_BIND:
             if d.node_name not in nodes:
                 continue  # the applicator's counted skip
-            pod = _own_pod(d.pod)
+            pod = own_pod(d.pod)
             pod["spec"]["nodeName"] = d.node_name
             # rebind: drop the stale entry so bind ORDER stays the
             # replay order of the surviving binding
@@ -552,7 +538,7 @@ def materialize(base_nodes: List[dict], deltas: List[ClusterDelta]) -> Materiali
             if bound.pop(d.pod_key, None) is None:
                 pending.pop(d.pod_key, None)
         elif d.kind == POD_ARRIVE:
-            pending[d.pod_key] = _own_pod(d.pod)
+            pending[d.pod_key] = own_pod(d.pod)
         else:  # pod_delete
             pending.pop(d.pod_key, None)
     return Materialized(
@@ -573,9 +559,9 @@ def cold_reload(cluster, deltas: List[ClusterDelta], engine: str = "oracle") -> 
     app = MirrorApplicator(cold_cluster, engine=engine)
     for pod in m.bound:
         # deep-own: place_existing_pod may stamp GPU annotations
-        p = _own_pod(pod)
+        p = own_pod(pod)
         app.oracle.place_existing_pod(p)
         app._bound[_pod_key(p)] = (p.get("spec") or {}).get("nodeName") or ""
     for pod in m.pending:
-        app.pending[_pod_key(pod)] = _own_pod(pod)
+        app.pending[_pod_key(pod)] = own_pod(pod)
     return app

@@ -450,7 +450,8 @@ def twin_materialized_digest(payload: dict) -> str:
     NodeStates), so this digest matching the live mirror's proves the
     payload restores to the same capacity state."""
     from ..models.decode import ResourceTypes
-    from .deltas import _own_pod, _pod_key, state_dict
+    from ..models.workloads import own_pod
+    from .deltas import _pod_key, state_dict
 
     cold = ResourceTypes()
     cold.nodes = [copy.deepcopy(n) for n in payload.get("nodes", [])]
@@ -458,11 +459,11 @@ def twin_materialized_digest(payload: dict) -> str:
     cold.priority_classes = copy.deepcopy(payload.get("priorityClasses", []))
     app = MirrorApplicator(cold, engine="oracle")
     for pod in payload.get("bound", []):
-        p = _own_pod(pod)
+        p = own_pod(pod)
         app.oracle.place_existing_pod(p)
         app._bound[_pod_key(p)] = (p.get("spec") or {}).get("nodeName") or ""
     for pod in payload.get("pending", []):
-        app.pending[_pod_key(pod)] = _own_pod(pod)
+        app.pending[_pod_key(pod)] = own_pod(pod)
     return config_fingerprint(state_dict(app))
 
 
@@ -473,7 +474,8 @@ def restore_mirror_state(mirror: ClusterMirror, payload: dict, seq: int):
     oracle over the payload nodes, re-place the bound pods, refill the
     pending queue and the bound index, and pin ``delta_seq`` so the
     journal suffix replay skips exactly the absorbed prefix."""
-    from .deltas import _own_pod, _pod_key
+    from ..models.workloads import own_pod
+    from .deltas import _pod_key
 
     with mirror.lock:
         app = mirror.applicator
@@ -481,13 +483,13 @@ def restore_mirror_state(mirror: ClusterMirror, payload: dict, seq: int):
         app.pending.clear()
         app._bound.clear()
         for pod in payload.get("bound", []):
-            p = _own_pod(pod)
+            p = own_pod(pod)
             app.oracle.place_existing_pod(p)
             app._bound[_pod_key(p)] = (
                 (p.get("spec") or {}).get("nodeName") or ""
             )
         for pod in payload.get("pending", []):
-            app.pending[_pod_key(pod)] = _own_pod(pod)
+            app.pending[_pod_key(pod)] = own_pod(pod)
         mirror.delta_seq = int(seq)
 
 

@@ -57,18 +57,14 @@ def _unbind(pod: dict) -> dict:
 
 
 def _expand_apps(apps, nodes: List[dict]) -> List[dict]:
-    """Expand request apps exactly like a standalone run (the serve
-    Session's expansion: counter reset, apps in order, each app's pods
-    through the queue sorts)."""
+    """Expand request apps like a standalone run (scheduler/queues
+    expand_apps) with the counter reset; queries are probes, so no
+    PrioritySort."""
     from ..models import workloads as wl
-    from ..scheduler.core import _sort_app_pods
+    from ..scheduler.queues import expand_apps
 
     wl.reset_name_counter()
-    pods: List[dict] = []
-    for app in apps:
-        app_pods = wl.generate_valid_pods_from_app(app.name, app.resource, nodes)
-        pods.extend(_sort_app_pods(app_pods))
-    return pods
+    return expand_apps(apps, nodes)[0]
 
 
 def _scan_pods(mirror, pods: List[dict], valid: Optional[np.ndarray]) -> np.ndarray:

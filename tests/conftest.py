@@ -19,7 +19,9 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
-REFERENCE_EXAMPLES = "/root/reference/example"
+# hand-written stand-ins for the reference's example apps
+# (tests/data/reference/application: simple, open_local, more_pods)
+REFERENCE_EXAMPLES = os.path.join(os.path.dirname(__file__), "data", "reference")
 
 
 @pytest.fixture(autouse=True)

@@ -273,3 +273,40 @@ def test_bound_clone_counter_and_metrics_line():
         f"simon_expand_bound_clones_total {COUNTERS.get('expand_bound_clones_total')}"
         in lines
     )
+
+
+def test_index_and_default_groups_encode_alike():
+    """The expansion index and the one-group-per-pod default encode the
+    same batch: the same pins, the same class of every pod with the
+    same class content, and the same placements from the scan."""
+    from open_simulator_tpu.scheduler.engine import TpuEngine
+
+    nodes = [_node(i) for i in range(6)]
+    raw = [
+        _pod("web-0", "web", node="n0003"),
+        _pod("db-0", "db", cpu="300m", anti=True),
+        _pod("web-1", "web"),
+        _pod("web-2", "web", node="n0001"),
+        _pod("db-1", "db", cpu="300m", anti=True),
+        _pod("spread-0", "spread", spread=True),
+        _pod("web-3", "web"),
+        _pod("spread-1", "spread", spread=True),
+    ]
+    pods, index = _expand(raw)
+    assert len(index.firsts) < len(pods)
+
+    def encode(groups):
+        oracle = Oracle(copy.deepcopy(nodes))
+        batch = encode_batch(oracle, encode_cluster(oracle), pods, groups=groups)
+        eng = TpuEngine(oracle)
+        eng.begin_batch(pods, groups=groups)
+        return batch, eng.scan_active(np.ones(len(pods), dtype=bool))
+
+    grouped, placed_g = encode(index.groups())
+    default, placed_d = encode(None)
+    assert grouped.pinned_node.tolist() == default.pinned_node.tolist()
+    assert grouped.class_of_pod.tolist() == default.class_of_pod.tolist()
+    assert grouped.u == default.u
+    for field in ("req_mcpu", "req_mem", "static_feasible", "want_ports"):
+        assert np.array_equal(getattr(grouped, field), getattr(default, field)), field
+    assert placed_g.tolist() == placed_d.tolist()

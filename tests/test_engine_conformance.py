@@ -2,6 +2,7 @@
 placements pod-for-pod (the bit-match contract from SURVEY.md §7).
 """
 
+import os
 import random
 
 import pytest
@@ -11,9 +12,12 @@ from open_simulator_tpu.models.cluster import cluster_from_config_dir
 from open_simulator_tpu.models.decode import load_directory
 from open_simulator_tpu.scheduler.core import simulate, AppResource
 
-DEMO = "/root/reference/example/cluster/demo_1"
-GPUSHARE = "/root/reference/example/cluster/gpushare"
-APPS = "/root/reference/example/application"
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEMO = os.path.join(_REPO, "example", "cluster", "demo")
+GPUSHARE = os.path.join(_REPO, "example", "cluster", "gpushare")
+GPUSHARE_APP = os.path.join(_REPO, "example", "application", "gpushare")
+# hand-written stand-ins for the reference's example apps
+APPS = os.path.join(_REPO, "tests", "data", "reference", "application")
 
 
 def _placements(result):
@@ -58,7 +62,7 @@ def test_demo1_overflow_conformance():
 
 def test_gpushare_conformance():
     cluster = cluster_from_config_dir(GPUSHARE)
-    _compare(cluster, [AppResource("gpushare", load_directory(f"{APPS}/gpushare"))])
+    _compare(cluster, [AppResource("gpushare", load_directory(GPUSHARE_APP))])
 
 
 def _random_node(rng, i):

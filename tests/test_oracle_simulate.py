@@ -6,6 +6,7 @@ GPU-share placements must respect per-device memory.
 """
 
 import json
+import os
 
 from open_simulator_tpu.models.cluster import cluster_from_config_dir
 from open_simulator_tpu.models.decode import load_directory
@@ -18,9 +19,12 @@ from open_simulator_tpu.models.storage import (
 )
 from open_simulator_tpu.scheduler.core import simulate, AppResource
 
-DEMO = "/root/reference/example/cluster/demo_1"
-GPUSHARE = "/root/reference/example/cluster/gpushare"
-APPS = "/root/reference/example/application"
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEMO = os.path.join(_REPO, "example", "cluster", "demo")
+GPUSHARE = os.path.join(_REPO, "example", "cluster", "gpushare")
+GPUSHARE_APP = os.path.join(_REPO, "example", "application", "gpushare")
+# hand-written stand-ins for the reference's example apps
+APPS = os.path.join(_REPO, "tests", "data", "reference", "application")
 
 
 def test_demo1_simple_all_scheduled():
@@ -73,7 +77,7 @@ def test_master_pods_tolerate_master_taint():
 
 def test_gpushare_device_accounting():
     cluster = cluster_from_config_dir(GPUSHARE)
-    app = AppResource(name="gpushare", resource=load_directory(f"{APPS}/gpushare"))
+    app = AppResource(name="gpushare", resource=load_directory(GPUSHARE_APP))
     res = simulate(cluster, [app])
     # every placed GPU pod has a device assignment, and per-device usage
     # never exceeds per-device memory

@@ -308,7 +308,8 @@ def test_gpu_and_storage_and_terms_in_one_kernel():
     from open_simulator_tpu.models import workloads as wl
     from open_simulator_tpu.models.decode import ResourceTypes
     from open_simulator_tpu.models.workloads import reset_name_counter
-    from open_simulator_tpu.scheduler.core import _sort_app_pods
+    from open_simulator_tpu.scheduler.core import AppResource
+    from open_simulator_tpu.scheduler.queues import expand_apps
     from open_simulator_tpu.testing import build_affinity_stress, with_node_gpu
 
     reset_name_counter()
@@ -332,7 +333,7 @@ def test_gpu_and_storage_and_terms_in_one_kernel():
             )
     res = ResourceTypes()
     res.stateful_sets = stss
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("m", res, nodes))
+    pods = expand_apps([AppResource("m", res)], nodes)[0]
     import copy
 
     for i, pod in enumerate(pods):

@@ -8,7 +8,6 @@ import pytest
 
 jnp = pytest.importorskip("jax.numpy")
 
-from open_simulator_tpu.models import workloads as wl
 from open_simulator_tpu.models.decode import ResourceTypes
 from open_simulator_tpu.models.workloads import reset_name_counter
 from open_simulator_tpu.ops import pallas_scan
@@ -21,7 +20,8 @@ from open_simulator_tpu.ops.encode import (
     to_scan_static,
     to_scan_state,
 )
-from open_simulator_tpu.scheduler.core import _sort_app_pods
+from open_simulator_tpu.scheduler.core import AppResource
+from open_simulator_tpu.scheduler.queues import expand_apps
 from open_simulator_tpu.scheduler.oracle import Oracle
 
 ZONES = ["a", "b", "c", "d"]
@@ -102,7 +102,7 @@ def check_case(
     reset_name_counter()
     res = ResourceTypes()
     res.stateful_sets = workloads
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("t", res, nodes))
+    pods = expand_apps([AppResource("t", res)], nodes)[0]
     if mutate_pods is not None:
         mutate_pods(pods)
     oracle = Oracle(nodes)
@@ -349,7 +349,7 @@ def test_affinity_stress_slice():
     nodes, stss = build_affinity_stress(n_nodes=24, n_sts=6, replicas=4, zones=3)
     res = ResourceTypes()
     res.stateful_sets = stss
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("t", res, nodes))
+    pods = expand_apps([AppResource("t", res)], nodes)[0]
     oracle = Oracle(nodes)
     cluster = encode_cluster(oracle)
     batch = encode_batch(oracle, cluster, pods)
@@ -403,7 +403,7 @@ def test_many_classes_beyond_128():
     res = ResourceTypes()
     res.stateful_sets = stss
     reset_name_counter()
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("t", res, nodes))
+    pods = expand_apps([AppResource("t", res)], nodes)[0]
     add_unique_classes(pods)
     oracle = Oracle(nodes)
     cluster = encode_cluster(oracle)

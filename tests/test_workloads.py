@@ -2,12 +2,16 @@
 checks of the reference unit test (pkg/simulator/core_test.go:364-591
 checkResult)."""
 
+import os
+
 from open_simulator_tpu.models.decode import load_directory
 from open_simulator_tpu.models import workloads as wl
 
+SIMPLE = os.path.join(os.path.dirname(__file__), "data", "reference", "application", "simple")
+
 
 def _simple():
-    return load_directory("/root/reference/example/application/simple")
+    return load_directory(SIMPLE)
 
 
 def test_deployment_expansion_count_and_metadata():

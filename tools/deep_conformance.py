@@ -22,7 +22,6 @@ import copy
 import numpy as np
 
 import jax.numpy as jnp
-from open_simulator_tpu.models import workloads as wl
 from open_simulator_tpu.models.decode import ResourceTypes
 from open_simulator_tpu.ops import pallas_scan
 from open_simulator_tpu.ops import scan as scan_ops
@@ -34,7 +33,8 @@ from open_simulator_tpu.ops.encode import (
     to_scan_static,
     to_scan_state,
 )
-from open_simulator_tpu.scheduler.core import _sort_app_pods
+from open_simulator_tpu.scheduler.core import AppResource
+from open_simulator_tpu.scheduler.queues import expand_apps
 from open_simulator_tpu.scheduler.oracle import Oracle
 from open_simulator_tpu.models.workloads import reset_name_counter
 from open_simulator_tpu.testing import build_affinity_stress, with_node_gpu
@@ -104,7 +104,7 @@ for seed in range(12):
             )
     res = ResourceTypes()
     res.stateful_sets = stss
-    pods = _sort_app_pods(wl.generate_valid_pods_from_app("d", res, nodes))
+    pods = expand_apps([AppResource("d", res)], nodes)[0]
     for i, pod in enumerate(pods):
         k = rng.randint(0, 30)
         if use_gpu:

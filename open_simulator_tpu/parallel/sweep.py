@@ -893,26 +893,40 @@ class CapacitySweep:
                 "pods": int(d.pod_cnt.sum()),
                 "vg": int(d.vg_used.sum()),
             }
-            for count in range(0, self.max_count + 1):
-                valid = self.node_valid(count)
-                active = self.pod_active(valid)
-                ok = True
-                for r in ("mcpu", "mem", "eph", "pods"):
-                    if base_used[r] + int(req[r][active].sum()) > int(alloc[r][valid].sum()):
-                        ok = False
-                        break
-                if ok:
-                    for r, cap in (("mcpu", max_cpu), ("mem", max_mem), ("vg", max_vg)):
-                        total_alloc = int(alloc[r][valid].sum())
-                        if total_alloc == 0:
-                            continue
-                        used = base_used[r] + int(req[r][active].sum())
-                        if int(used / total_alloc * 100) > cap:
-                            ok = False
-                            break
-                if ok:
-                    return count
-            return self.max_count
+            # every count 0..max_count at once, as prefix sums over the
+            # candidate axis: slot j + 1 holds what candidate n_base + j
+            # brings. A pod counts at every count when its _ds_target is
+            # below n_base (-1 included), else from its candidate on
+            nb, mc = self.n_base, self.max_count
+            tgt = self._ds_target
+            on_new = tgt >= nb
+            slot = tgt[on_new] - nb + 1
+
+            def used_at(r):
+                added = np.zeros(mc + 1, dtype=np.int64)
+                np.add.at(added, slot, req[r][on_new])
+                return base_used[r] + int(req[r][~on_new].sum()) + np.cumsum(added)
+
+            def alloc_at(r):
+                a = np.asarray(alloc[r], dtype=np.int64)
+                return int(a[:nb].sum()) + np.concatenate(([0], np.cumsum(a[nb:])))
+
+            used = {r: used_at(r) for r in req}
+            total = {r: alloc_at(r) for r in alloc}
+            ok = np.ones(mc + 1, dtype=bool)
+            for r in ("mcpu", "mem", "eph", "pods"):
+                ok &= used[r] <= total[r]
+            for r, cap in (("mcpu", max_cpu), ("mem", max_mem), ("vg", max_vg)):
+                u, t = used[r], total[r]
+                has = t != 0
+                share = np.divide(u, t, out=np.zeros(mc + 1), where=has) * 100
+                over = has & (np.trunc(share) > cap)
+                # float64 division rounds like Python's int division
+                # only while both ints are below 2**53
+                for c in np.flatnonzero(has & ((u >= 2**53) | (t >= 2**53))):
+                    over[c] = int(int(u[c]) / int(t[c]) * 100) > cap
+                ok &= ~over
+            return int(np.argmax(ok)) if ok.any() else mc
 
     # -- minimal-count search ----------------------------------------------
 
